@@ -8,10 +8,11 @@ delivery survives the ``ShardedFile`` segment table); the per-step wall
 ratio is the FileSet manifest's overhead on a read-bound drain, and
 ``ShardMetrics.shard_bytes`` must account for every physical byte per shard.
 
-Part B — **sharded staged-bytes accounting**, on an 8-device host mesh
-(``--xla_force_host_platform_device_count`` — the flag must be set before
-jax initialises, so ``run()`` re-execs this file in a fresh interpreter
-when the current process already holds a smaller backend). A streaming
+Part B — **sharded staged-bytes accounting**, on a mesh of every device
+this process holds: 8 host devices on the CPU, which the command line asks
+for before JAX starts (``XLA_FLAGS=--xla_force_host_platform_device_count=8``),
+or the chips present on an accelerator. With fewer, the script refuses and
+says what it found. A streaming
 pipeline built with ``sharding=`` (constructor) places every splinter chunk
 against the device spans as its read lands: total staged bytes == 1x the
 window per step, per-device max == window/ndev, zero cross-host
@@ -24,7 +25,8 @@ restage); the report records both ledgers side by side.
 
 Writes ``BENCH_fileset.json`` at the repo root (full mode).
 
-Usage: python benchmarks/perf_fileset.py [--quick]
+Usage: XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+           python benchmarks/perf_fileset.py [--quick]
 """
 from __future__ import annotations
 
@@ -32,20 +34,12 @@ import argparse
 import json
 import os
 import statistics
-import subprocess
 import sys
 import time
 import warnings
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
-NDEV = 8
-_FLAG = f"--xla_force_host_platform_device_count={NDEV}"
-if "jax" not in sys.modules and _FLAG not in os.environ.get("XLA_FLAGS", ""):
-    # Must land before jax initialises its backend; harmless on re-import.
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()
 
 import numpy as np
 
@@ -55,6 +49,7 @@ from repro.data import CkIOPipeline, FileSet, make_token_file
 from repro.data.fileset import write_token_shards
 from repro.data.tokenfile import HEADER_BYTES
 
+HOST_DEVICES = 8       # the CPU run's virtual devices
 NUM_PES = 4
 NUM_READERS = 4
 WARM_STEPS = 1
@@ -121,11 +116,27 @@ def drain_host(source, wl: dict):
     return statistics.median(steps_s), batches, copied, shards
 
 
-def _mesh_sharding(flat: bool = False):
+def mesh_devices() -> list:
+    """The devices Part B shards over: 8 host devices on the CPU, or every
+    accelerator present. Raises with what was found when there are fewer."""
     import jax
+
+    devs = jax.devices()
+    want = HOST_DEVICES if devs[0].platform == "cpu" else 2
+    if len(devs) < want:
+        hint = (f"; start the process with XLA_FLAGS=--xla_force_host_"
+                f"platform_device_count={HOST_DEVICES}"
+                if devs[0].platform == "cpu" else "")
+        raise RuntimeError(
+            f"perf_fileset needs {want} {devs[0].platform} devices, found "
+            f"{len(devs)}{hint}")
+    return devs[:HOST_DEVICES] if devs[0].platform == "cpu" else devs
+
+
+def _mesh_sharding(devs, flat: bool = False):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-    devs = np.array(jax.devices()[:NDEV])
+    devs = np.array(devs)
     # The constructor path shards the assembled (batch, seq+1) window; the
     # legacy per-call path forwards the sharding to a device_put of the
     # *flat* 1-D token window, so it needs the rank-1 spec.
@@ -133,15 +144,15 @@ def _mesh_sharding(flat: bool = False):
     return NamedSharding(Mesh(devs, ("dp",)), spec)
 
 
-def run_sharded(fs: FileSet, wl: dict, constructor: bool):
-    """Streamed drain into an 8-device batch sharding.
+def run_sharded(fs: FileSet, wl: dict, devs, constructor: bool):
+    """Streamed drain into a batch sharding over ``devs``.
 
     ``constructor=True`` ships the sharding at pipeline construction (this
     PR's path: per-chunk placement); ``False`` passes it per call (the
     legacy warn-and-restage fallback). Returns batches + both ledgers."""
     import jax
 
-    sh = _mesh_sharding(flat=not constructor)
+    sh = _mesh_sharding(devs, flat=not constructor)
     pipe = _pipe(fs, wl, streaming=True,
                  sharding=sh if constructor else None)
     rt_warnings = 0
@@ -171,40 +182,9 @@ def _match(a, b) -> bool:
                for (x1, y1), (x2, y2) in zip(a, b))
 
 
-def _reexec(quick: bool) -> dict:
-    """Fresh interpreter: the device-count flag only works pre-jax-init."""
-    if os.environ.get("CKIO_FILESET_REEXEC"):
-        raise RuntimeError(
-            f"re-exec still sees < {NDEV} devices; XLA_FLAGS did not take")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + _FLAG).strip()
-    env["CKIO_FILESET_REEXEC"] = "1"
-    cmd = [sys.executable, os.path.abspath(__file__)]
-    if quick:
-        cmd.append("--quick")
-    subprocess.run(cmd, check=True, env=env)
-    out = (os.path.join(common.BENCH_DIR, "BENCH_fileset.quick.json")
-           if quick else
-           os.path.join(os.path.dirname(os.path.dirname(
-               os.path.abspath(__file__))), "BENCH_fileset.json"))
-    with open(out) as f:
-        return json.load(f)
-
-
 def run(quick: bool = False) -> dict:
-    import jax
-
-    if jax.device_count() < NDEV:
-        # jax was already initialised (run.py imports earlier benchmarks)
-        # with the default single CPU device — the flag can no longer take
-        # effect in this process, so run the measurement in a child.
-        report = _reexec(quick)
-        common.emit("fileset_drain_ratio", 0.0,
-                    f"{report['drain']['fileset_over_single']:.3f}x")
-        common.emit("fileset_staged_ratio", 0.0,
-                    f"{report['sharded_staging']['legacy_over_ctor']:.2f}x")
-        return report
-
+    devs = mesh_devices()
+    ndev = len(devs)
     wl = workload(quick)
     single, fs, _ = build_corpus(wl)
     window_bytes = wl["global_batch"] * (wl["seq_len"] + 1) * 4
@@ -216,11 +196,11 @@ def run(quick: bool = False) -> dict:
     drain_match = _match(ref_batches, fs_batches)
     total_read = (WARM_STEPS + wl["steps"]) * window_bytes
 
-    # -- Part B: staged-bytes accounting on the 8-device mesh --------------
+    # -- Part B: staged-bytes accounting on the device mesh ----------------
     ctor_b, ctor_sh, ctor_dev, ctor_strm, ctor_ing, ctor_warn = run_sharded(
-        fs, wl, constructor=True)
+        fs, wl, devs, constructor=True)
     leg_b, _, _, leg_strm, leg_ing, leg_warn = run_sharded(
-        fs, wl, constructor=False)
+        fs, wl, devs, constructor=False)
     measured = (WARM_STEPS + wl["steps"]) * window_bytes
     ctor_staged = int(ctor_sh["addressable_bytes"])
     # The stager also places the *prefetched* next window's chunks (the
@@ -228,9 +208,9 @@ def run(quick: bool = False) -> dict:
     # consumed share by whole windows — the invariant is perfect balance:
     # every device staged exactly total/ndev.
     total_puts = sum(ctor_dev.values())
-    balanced = (len(ctor_dev) == NDEV
+    balanced = (len(ctor_dev) == ndev
                 and max(ctor_dev.values()) == min(ctor_dev.values())
-                and max(ctor_dev.values()) == total_puts // NDEV)
+                and max(ctor_dev.values()) == total_puts // ndev)
     # Legacy fallback ledger: streamed chunks staged to the default device
     # while reads landed (then discarded), plus the whole-window restage
     # that satisfies the per-call sharding.
@@ -238,7 +218,8 @@ def run(quick: bool = False) -> dict:
 
     report = {
         "bench": "perf_fileset",
-        "devices": NDEV,
+        "devices": ndev,
+        "platform": devs[0].platform,
         "workload": {**wl, "window_bytes": window_bytes,
                      "num_readers": NUM_READERS,
                      "shard_weights": list(SHARD_WEIGHTS[:wl["num_shards"]])},
@@ -266,7 +247,7 @@ def run(quick: bool = False) -> dict:
                 "staged_put_bytes": int(total_puts),
                 "prefetched_bytes": int(total_puts - ctor_staged),
                 "max_device_bytes": int(ctor_sh["max_device_bytes"]),
-                "per_device_bytes": total_puts // NDEV,
+                "per_device_bytes": total_puts // ndev,
                 "devices_staged": int(ctor_sh["devices_staged"]),
                 "device_put_calls": int(ctor_sh["device_put_calls"]),
                 "cross_host_placements": int(ctor_sh["cross_host_placements"]),
@@ -291,8 +272,8 @@ def run(quick: bool = False) -> dict:
         },
         "note": "Part A: one stream as a single file vs an uneven "
                 "FileSet — bit-identical whole-window drains, zero "
-                "bytes_copied, per-shard read accounting. Part B (8 host "
-                "devices): constructor sharding stages exactly 1x window "
+                "bytes_copied, per-shard read accounting. Part B (every "
+                "mesh device): constructor sharding stages exactly 1x window "
                 "per step at window/ndev per device with no warning; the "
                 "legacy per-call fallback warns and pays ~2x (streamed "
                 "chunks discarded + whole-window restage).",
@@ -314,7 +295,10 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="small window / fewer steps (CI smoke)")
     args = ap.parse_args()
-    report = run(quick=args.quick)
+    try:
+        report = run(quick=args.quick)
+    except RuntimeError as e:
+        raise SystemExit(str(e)) from None
     sh = report["sharded_staging"]
     ok = (report["drain"]["batches_match"]
           and report["drain"]["bytes_copied"] == 0

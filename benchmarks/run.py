@@ -123,7 +123,8 @@ def perf_fileset() -> None:
     # vs the same stream as one file — bit-identical, zero-copy — plus the
     # 8-device sharded staged-bytes ledger: constructor sharding stages 1x
     # the window, balanced across devices; the legacy per-call fallback
-    # pays ~2x). Re-execs itself for the 8-device host mesh.
+    # pays ~2x). On the CPU it needs the 8-device host mesh from
+    # XLA_FLAGS=--xla_force_host_platform_device_count=8 set before start.
     from benchmarks import perf_fileset as m
     m.run(quick=common.QUICK)
 

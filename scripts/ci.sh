@@ -100,7 +100,8 @@ echo "== recovery benchmark (smoke, mid-drain SIGKILL) =="
 python benchmarks/perf_recovery.py --quick
 
 echo "== fileset benchmark (smoke, sharded sessions + staged-bytes ledger) =="
-python benchmarks/perf_fileset.py --quick
+XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    python benchmarks/perf_fileset.py --quick
 
 echo "== reader-service benchmark (smoke, pooled re-arm vs spawn) =="
 python benchmarks/perf_service.py --quick

@@ -20,19 +20,24 @@ bandwidth. Three kernels cover the ingest pipeline:
     Fused batch-major reassembly of an LM step window: a file-order token
     buffer (at any token offset ``window_tok_off``) becomes ``(inputs,
     labels)`` of shape ``(B, S)`` in one kernel — the label shift-by-one
-    rides the same gather, and remainder windows (``valid_limit``) are
-    padded with ``pad_id`` on device. Each output row touches at most two
-    consecutive ``(S+1)``-token blocks of the source, so the kernel needs no
-    dynamic slicing: the split point ``r = window_tok_off % (S+1)`` is
-    static per call.
+    rides the same copy, and remainder windows (``valid_limit``) are padded
+    with ``pad_id`` on device. The window is viewed as ``(B, S+1)`` rows
+    starting at its own offset, and each grid step moves one block of
+    ``_ROWS`` whole rows.
 
 ``reassemble_tokens_pallas``
     General token-level gather for staged layouts whose splinter boundaries
     do *not* align to uniform blocks: per output row a precomputed
     ``(B, S+1)`` index row gathers from the full staged buffer (``-1`` =
-    pad). The staged buffer is materialized whole per grid step, so this
-    path is bounded by VMEM (fine for per-host step windows); the block
-    kernels above are preferred whenever the layout permits.
+    pad). The staged buffer is resident in VMEM whole, so this path is
+    bounded by VMEM (fine for per-host step windows), and it moves one token
+    per loop iteration; the block kernels above are preferred whenever the
+    layout permits.
+
+TPU tiling shapes every block here: the last two block dims must be
+multiples of ``(8, 128)`` or span the whole array, so rows move in blocks
+of ``_ROWS`` (batches are padded up to it and the padding sliced off), and
+1-D token rows are given a unit middle axis.
 """
 from __future__ import annotations
 
@@ -42,6 +47,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+_ROWS = 8      # sublanes of one TPU vreg
+_LANES = 128   # lanes of one TPU vreg
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def _gather_kernel(idx_ref, src_ref, out_ref):
@@ -58,6 +70,11 @@ def reassemble_pallas(
     """Block gather ``out[i] = src[idx[i]]`` over the leading axis."""
     if src.ndim < 2:
         raise ValueError(f"src must have >= 2 dims (got shape {src.shape})")
+    if src.ndim == 2:
+        # A (1, T) block of an (NB, T) array breaks the sublane tiling; as
+        # (NB, 1, T) each block's last two dims span the whole array.
+        return reassemble_pallas(src[:, None, :], idx,
+                                 interpret=interpret)[:, 0, :]
     rest = src.shape[1:]
     NBo = idx.shape[0]
     zeros = (0,) * len(rest)
@@ -66,9 +83,10 @@ def reassemble_pallas(
         num_scalar_prefetch=1,
         grid=(NBo,),
         in_specs=[
-            pl.BlockSpec((1,) + rest, lambda i, idx_ref: (idx_ref[i],) + zeros),
+            pl.BlockSpec((None,) + rest,
+                         lambda i, idx_ref: (idx_ref[i],) + zeros),
         ],
-        out_specs=pl.BlockSpec((1,) + rest, lambda i, idx_ref: (i,) + zeros),
+        out_specs=pl.BlockSpec((None,) + rest, lambda i, idx_ref: (i,) + zeros),
     )
     return pl.pallas_call(
         _gather_kernel,
@@ -91,83 +109,51 @@ def reassemble_window_pallas(
     """File-order token buffer -> batch-major ``(inputs, labels)``, fused.
 
     Output row ``b`` covers flat positions ``window_tok_off + b*(S+1) + j``;
-    ``labels`` are the same gather shifted by one token. Positions at or
-    beyond ``valid_limit`` (absolute, in ``linear`` coordinates — remainder
-    final windows) read as ``pad_id``. All split points are static, so each
-    row is assembled from two consecutive ``(S+1)``-token source blocks with
-    no dynamic slicing.
+    ``labels`` are the same row shifted by one token. Positions at or beyond
+    ``valid_limit`` (absolute, in ``linear`` coordinates — remainder final
+    windows) read as ``pad_id``. The window's offset is applied where the
+    buffer is viewed as ``(B, S+1)`` rows, so every row is one source row
+    whatever the offset.
     """
     B, S = global_batch, seq_len
     S1 = S + 1
-    q0, r = divmod(window_tok_off, S1)
-    full_limit = window_tok_off + B * S1
+    Bp = _round_up(B, _ROWS)
+    w0 = window_tok_off
+    full_limit = w0 + B * S1
     if valid_limit is None:
         valid_limit = full_limit
     mask_tail = valid_limit < full_limit
 
-    def masked(i, inp, lab):
-        if not mask_tail:
-            return inp, lab
-        pad = jnp.asarray(pad_id, dtype=inp.dtype)
-        base = window_tok_off + i * S1
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
-        return (jnp.where(pos < valid_limit, inp, pad),
-                jnp.where(pos + 1 < valid_limit, lab, pad))
-
-    out = jax.ShapeDtypeStruct((B, S), linear.dtype)
-    out_specs = [
-        pl.BlockSpec((1, S), lambda b: (b, 0)),
-        pl.BlockSpec((1, S), lambda b: (b, 0)),
-    ]
+    need = w0 + Bp * S1
     L = linear.shape[0]
-
-    if r == 0:
-        # Row-aligned window (the pipeline hot path): each output row is
-        # exactly one source block — no second block, and no pad copy
-        # unless this is a remainder window.
-        need = (q0 + B) * S1
-        if L < need:
-            linear = jnp.pad(linear, (0, need - L), constant_values=pad_id)
-        lin2 = linear[:need].reshape(q0 + B, S1)
-
-        def kern1(a_ref, inp_ref, lab_ref):
-            i = pl.program_id(0)
-            seg = a_ref[...]                                   # (1, S1)
-            inp_ref[...], lab_ref[...] = masked(i, seg[:, :S], seg[:, 1:])
-
-        return pl.pallas_call(
-            kern1,
-            grid=(B,),
-            in_specs=[pl.BlockSpec((1, S1), lambda b: (q0 + b, 0))],
-            out_specs=out_specs,
-            out_shape=[out, out],
-            interpret=interpret,
-        )(lin2)
-
-    # Unaligned window: row b spans source blocks q0+b and q0+b+1; pad so
-    # the +1 block exists.
-    need = (q0 + B + 1) * S1
     if L < need:
         linear = jnp.pad(linear, (0, need - L), constant_values=pad_id)
-    lin2 = linear[:need].reshape(q0 + B + 1, S1)
+    rows = linear[w0:need].reshape(Bp, S1)
 
-    def kern2(a_ref, b_ref, inp_ref, lab_ref):
-        i = pl.program_id(0)
-        cat = jnp.concatenate([a_ref[...], b_ref[...]], axis=1)  # (1, 2*S1)
-        seg = cat[:, r : r + S1 + 1]                             # (1, S1+1)
-        inp_ref[...], lab_ref[...] = masked(i, seg[:, :S], seg[:, 1 : S + 1])
+    def kern(a_ref, inp_ref, lab_ref):
+        seg = a_ref[...]                                       # (_ROWS, S1)
+        inp, lab = seg[:, :S], seg[:, 1:]
+        if mask_tail:
+            pad = jnp.asarray(pad_id, dtype=seg.dtype)
+            row = jax.lax.broadcasted_iota(jnp.int32, (_ROWS, S), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (_ROWS, S), 1)
+            pos = w0 + (pl.program_id(0) * _ROWS + row) * S1 + col
+            inp = jnp.where(pos < valid_limit, inp, pad)
+            lab = jnp.where(pos + 1 < valid_limit, lab, pad)
+        inp_ref[...] = inp
+        lab_ref[...] = lab
 
-    return pl.pallas_call(
-        kern2,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, S1), lambda b: (q0 + b, 0)),
-            pl.BlockSpec((1, S1), lambda b: (q0 + b + 1, 0)),
-        ],
-        out_specs=out_specs,
+    out = jax.ShapeDtypeStruct((Bp, S), linear.dtype)
+    out_spec = pl.BlockSpec((_ROWS, S), lambda b: (b, 0))
+    inputs, labels = pl.pallas_call(
+        kern,
+        grid=(Bp // _ROWS,),
+        in_specs=[pl.BlockSpec((_ROWS, S1), lambda b: (b, 0))],
+        out_specs=[out_spec, out_spec],
         out_shape=[out, out],
         interpret=interpret,
-    )(lin2, lin2)
+    )(rows)
+    return inputs[:B], labels[:B]
 
 
 def reassemble_tokens_pallas(
@@ -181,34 +167,61 @@ def reassemble_tokens_pallas(
 
     ``row_idx[b, j]`` is the staged position of window flat token
     ``b*(S+1)+j`` (``j`` in ``[0, S+1)`` — the last column only feeds the
-    label shift); negative entries pad. The whole staged buffer is resident
-    per grid step, so sizing is VMEM-bounded — use the block kernels when
-    the staged layout is block-uniform.
+    label shift); negative entries pad. The staged buffer sits whole in
+    VMEM as ``(L/128, 128)`` lanes; each grid step gathers one ``(8, 128)``
+    tile of the flattened index, reading its positions from SMEM and
+    rotating each source lane into place. Tokens travel as 32-bit lanes
+    (narrower integer tokens are widened and narrowed back).
     """
     B, S2 = row_idx.shape
     S = S2 - 1
     L = staged.shape[0]
+    dtype = staged.dtype
+    if dtype.itemsize == 4:
+        carrier = jax.lax.bitcast_convert_type(staged, jnp.int32)
+    elif jnp.issubdtype(dtype, jnp.integer):
+        carrier = staged.astype(jnp.int32)
+    else:
+        raise ValueError(f"token dtype {dtype} is not 32-bit or integer")
+    R = -(-L // _LANES)
+    table = jnp.pad(carrier, (0, R * _LANES - L)).reshape(R, _LANES)
+    tile = _ROWS * _LANES
+    flat = jnp.clip(row_idx, 0, L - 1).reshape(-1)
+    N = flat.shape[0]
+    Np = _round_up(N, tile)
+    flat = jnp.pad(flat, (0, Np - N))
 
-    def kern(idx_ref, st_ref, inp_ref, lab_ref):
-        idx = idx_ref[...]                                     # (1, S+1)
-        safe = jnp.clip(idx, 0, L - 1)
-        row = jnp.take(st_ref[...], safe[0], axis=0)[None, :]  # (1, S+1)
-        pad = jnp.asarray(pad_id, dtype=row.dtype)
-        inp_ref[...] = jnp.where(idx[:, :S] >= 0, row[:, :S], pad)
-        lab_ref[...] = jnp.where(idx[:, 1 : S + 1] >= 0, row[:, 1 : S + 1], pad)
+    def kern(idx_ref, tab_ref, out_ref):
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
 
-    out = jax.ShapeDtypeStruct((B, S), staged.dtype)
-    return pl.pallas_call(
+        def fill_row(r, carry):
+            def one(k, acc):
+                p = idx_ref[r * _LANES + k]
+                src = tab_ref[pl.ds(p // _LANES, 1), :]        # (1, 128)
+                moved = pltpu.roll(src, (k - p % _LANES) % _LANES, 1)
+                return jnp.where(lane == k, moved, acc)
+
+            out_ref[pl.ds(r, 1), :] = jax.lax.fori_loop(
+                0, _LANES, one, jnp.zeros((1, _LANES), jnp.int32))
+            return carry
+
+        jax.lax.fori_loop(0, _ROWS, fill_row, 0)
+
+    got = pl.pallas_call(
         kern,
-        grid=(B,),
+        grid=(Np // tile,),
         in_specs=[
-            pl.BlockSpec((1, S2), lambda b: (b, 0)),
-            pl.BlockSpec((L,), lambda b: (0,)),
+            pl.BlockSpec((tile,), lambda i: (i,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((R, _LANES), lambda i: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, S), lambda b: (b, 0)),
-            pl.BlockSpec((1, S), lambda b: (b, 0)),
-        ],
-        out_shape=[out, out],
+        out_specs=pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Np // _LANES, _LANES), jnp.int32),
         interpret=interpret,
-    )(row_idx, staged)
+    )(flat, table)
+    got = got.reshape(-1)[:N].reshape(B, S2)
+    rows = (jax.lax.bitcast_convert_type(got, dtype) if dtype.itemsize == 4
+            else got.astype(dtype))
+    pad = jnp.asarray(pad_id, dtype=dtype)
+    inputs = jnp.where(row_idx[:, :S] >= 0, rows[:, :S], pad)
+    labels = jnp.where(row_idx[:, 1:] >= 0, rows[:, 1:], pad)
+    return inputs, labels

@@ -146,10 +146,7 @@ def lower_cell(
     p_in = _with_sharding(p_abs, p_specs, mesh)
     batch_in = _batch_abstract(model, shape, mesh)
 
-    # jax >= 0.5 exposes jax.sharding.set_mesh; earlier versions enter the
-    # mesh context via the Mesh object itself.
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    with (set_mesh(mesh) if set_mesh is not None else mesh):
+    with jax.sharding.set_mesh(mesh):
         if shape.kind == "train":
             nmb = pick_num_microbatches(shape, mesh, num_microbatches)
             opt_abs = jax.eval_shape(

@@ -13,13 +13,10 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh():
-    """Whatever devices exist locally (tests / examples): 1-D data mesh."""
-    n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    # Activations carry sharding hints (models/layers.py shard_act) and the
+    # partitioner resolves the rest: the axes are Auto, not Explicit.
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def mesh_axis_sizes(mesh) -> dict:

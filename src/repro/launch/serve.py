@@ -27,6 +27,7 @@ from repro.configs.registry import get_config, smoke_config
 from repro.core import CkIO, FileOptions, ServeMetrics
 from repro.data import make_token_file, read_meta
 from repro.data.fileset import FileSet, write_token_shards
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve import (
     BatchServer,
@@ -187,6 +188,7 @@ def main() -> None:
     ap.add_argument("--shards", type=int, default=3,
                     help="prompt FileSet shard count (continuous mode)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -12,10 +12,12 @@ device_put with NamedSharding).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import sys
 import time
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +26,7 @@ import numpy as np
 from repro.configs.registry import get_config, smoke_config
 from repro.core import CkIO, FileOptions, Topology
 from repro.data import CkIOPipeline, make_token_file
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.train import (
     AsyncCheckpointer,
@@ -35,7 +37,9 @@ from repro.train import (
 )
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
+    """The driver's options, normalized (``--service`` implies the process
+    backend, ``--streaming`` implies ``--device-ingest``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="phi4-mini-3.8b")
     ap.add_argument("--smoke", action="store_true",
@@ -146,23 +150,7 @@ def main() -> None:
                          " readahead) per session from observed throughput;"
                          " the explicit flags then only seed the first"
                          " session")
-    args = ap.parse_args()
-    if args.tuned_env and not os.environ.get("CKIO_TUNED_ENV"):
-        # Re-exec through the env script so LD_PRELOAD (allocator) and
-        # XLA_FLAGS exist before the interpreter and jax start. env.sh
-        # exports CKIO_TUNED_ENV=1, which breaks the exec loop.
-        root = os.path.abspath(os.path.join(os.path.dirname(__file__),
-                                            "..", "..", ".."))
-        env_sh = os.path.join(root, "scripts", "env.sh")
-        if os.path.exists(env_sh):
-            argv = [sys.executable, "-m", "repro.launch.train",
-                    *sys.argv[1:]]
-            refs = " ".join(
-                ['"$0"'] + [f'"${{{i}}}"' for i in range(1, len(argv))])
-            os.execvp("bash", [
-                "bash", "-c", f'source "{env_sh}" && exec {refs}', *argv])
-        print(f"--tuned-env: {env_sh} not found; continuing untuned",
-              file=sys.stderr)
+    args = ap.parse_args(argv)
     if args.numa_pin and not args.topology:
         ap.error("--numa-pin requires --topology (the topology supplies "
                  "the domain->CPU map; without it nothing would be pinned)")
@@ -170,10 +158,46 @@ def main() -> None:
         args.backend = "process"
     if args.streaming:
         args.device_ingest = True
+    return args
 
+
+def _reexec_tuned_env() -> None:
+    # Re-exec through the env script so LD_PRELOAD (allocator) and
+    # XLA_FLAGS exist before the interpreter and jax start. env.sh exports
+    # CKIO_TUNED_ENV=1, which breaks the exec loop.
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                        "..", "..", ".."))
+    env_sh = os.path.join(root, "scripts", "env.sh")
+    if os.path.exists(env_sh):
+        argv = [sys.executable, "-m", "repro.launch.train", *sys.argv[1:]]
+        refs = " ".join(
+            ['"$0"'] + [f'"${{{i}}}"' for i in range(1, len(argv))])
+        os.execvp("bash", [
+            "bash", "-c", f'source "{env_sh}" && exec {refs}', *argv])
+    print(f"--tuned-env: {env_sh} not found; continuing untuned",
+          file=sys.stderr)
+
+
+def main() -> None:
+    args = parse_args()
+    if args.tuned_env and not os.environ.get("CKIO_TUNED_ENV"):
+        _reexec_tuned_env()
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
+    print(json.dumps(run(cfg, args), indent=2))
+
+
+def run(
+    cfg,
+    args: argparse.Namespace,
+    *,
+    on_batch: Optional[Callable[[int, dict], None]] = None,
+) -> dict:
+    """Train ``cfg`` for ``args.steps`` steps through the CkIO pipeline and
+    return the run summary. ``on_batch(step, batch)`` sees each step's
+    device batch before the step consumes it."""
     model = build_model(cfg)
     print(f"arch={cfg.name} layers={cfg.num_layers} d={cfg.d_model} "
           f"params≈{cfg.param_counts()['total']/1e6:.1f}M")
@@ -203,91 +227,106 @@ def main() -> None:
     topology = (Topology.from_spec(args.topology, num_pes=num_pes,
                                    pes_per_node=num_pes)
                 if args.topology else None)
-    service = None
-    if args.service:
-        from repro.ipc.service import ReaderService, ServiceOptions
+    with contextlib.ExitStack() as stack:
+        service = None
+        if args.service:
+            from repro.ipc.service import ReaderService, ServiceOptions
 
-        service = ReaderService(ServiceOptions(
-            pool_workers=args.pool_workers))
-        print(f"reader service: pool of {args.pool_workers} persistent "
-              f"workers (steady-state sessions re-arm, not respawn)")
-    pipe = CkIOPipeline(
-        data_source, args.global_batch, args.seq,
-        ckio=ckio, num_consumers=args.num_consumers,
-        file_opts=FileOptions(num_readers=args.num_readers,
-                              adaptive_splinters=args.adaptive_splinters,
-                              placement=args.placement,
-                              topology=topology,
-                              numa_pin=args.numa_pin,
-                              prefault_arena=(topology is not None
-                                              or args.backend == "process"),
-                              backend=args.backend,
-                              max_workers=args.max_workers,
-                              direct_io=args.direct_io,
-                              queue_depth=args.queue_depth,
-                              readahead_bytes=args.readahead_mb * (1 << 20),
-                              submit_mode=args.submit_mode,
-                              adaptive_queue=args.adaptive_queue),
-        service=service,
-        streaming=args.streaming,
-    )
+            service = ReaderService(ServiceOptions(
+                pool_workers=args.pool_workers))
+            stack.callback(service.shutdown)
+            print(f"reader service: pool of {args.pool_workers} persistent "
+                  f"workers (steady-state sessions re-arm, not respawn)")
+        pipe = CkIOPipeline(
+            data_source, args.global_batch, args.seq,
+            ckio=ckio, num_consumers=args.num_consumers,
+            file_opts=FileOptions(num_readers=args.num_readers,
+                                  adaptive_splinters=args.adaptive_splinters,
+                                  placement=args.placement,
+                                  topology=topology,
+                                  numa_pin=args.numa_pin,
+                                  prefault_arena=(topology is not None
+                                                  or args.backend == "process"),
+                                  backend=args.backend,
+                                  max_workers=args.max_workers,
+                                  direct_io=args.direct_io,
+                                  queue_depth=args.queue_depth,
+                                  readahead_bytes=args.readahead_mb * (1 << 20),
+                                  submit_mode=args.submit_mode,
+                                  adaptive_queue=args.adaptive_queue),
+            service=service,
+            streaming=args.streaming,
+        )
+        stack.callback(pipe.close)
 
-    # -- state -----------------------------------------------------------------
-    params = model.init(jax.random.PRNGKey(0))
-    opt = init_opt_state(params)
-    opt_cfg = OptConfig(peak_lr=args.lr, warmup_steps=max(2, args.steps // 10),
-                        decay_steps=args.steps)
-    step_jit = jax.jit(make_train_step(
-        model, opt_cfg, num_microbatches=args.microbatches,
-        compression=args.compression,
-    ))
+        # -- state -------------------------------------------------------------
+        params = model.init(jax.random.PRNGKey(0))
+        state = {"params": params, "opt": init_opt_state(params)}
+        opt_cfg = OptConfig(peak_lr=args.lr,
+                            warmup_steps=max(2, args.steps // 10),
+                            decay_steps=args.steps)
+        step_jit = jax.jit(make_train_step(
+            model, opt_cfg, num_microbatches=args.microbatches,
+            compression=args.compression,
+        ))
 
-    def step_fn(state, batch):
-        p, o, metrics = step_jit(state["params"], state["opt"], batch)
-        return {"params": p, "opt": o}, metrics
+        def step_fn(state, batch):
+            p, o, metrics = step_jit(state["params"], state["opt"], batch)
+            # The step consumes its input state: free it once the step is
+            # done with it, so that no reference left to the first state
+            # keeps a second copy of parameters and moments on the device.
+            for leaf in jax.tree.leaves(state):
+                if isinstance(leaf, jax.Array):
+                    leaf.delete()
+            return {"params": p, "opt": o}, metrics
 
-    def batch_for(step: int):
-        if args.device_ingest:
-            # Device path: one host→device transfer of the whole window,
-            # batch-major reassembly + label shift on device.
-            x, y = pipe.get_batch_device(step % pipe.num_steps)
-            return {"tokens": x, "labels": y}
-        x, y = pipe.get_batch(step % pipe.num_steps)
-        return {"tokens": jnp.asarray(x), "labels": jnp.asarray(y)}
+        def batch_for(step: int):
+            if args.device_ingest:
+                # Device path: one host→device transfer of the whole window,
+                # batch-major reassembly + label shift on device.
+                x, y = pipe.get_batch_device(step % pipe.num_steps)
+                batch = {"tokens": x, "labels": y}
+            else:
+                x, y = pipe.get_batch(step % pipe.num_steps)
+                batch = {"tokens": jnp.asarray(x), "labels": jnp.asarray(y)}
+            if on_batch is not None:
+                on_batch(step, batch)
+            return batch
 
-    ck = AsyncCheckpointer(args.ckpt_dir, keep=3)
-    sup = StepSupervisor(step_fn, ck, ckpt_every=args.ckpt_every)
+        ck = AsyncCheckpointer(args.ckpt_dir, keep=3)
+        stack.callback(ck.shutdown)
+        sup = StepSupervisor(step_fn, ck, ckpt_every=args.ckpt_every)
 
-    state = {"params": params, "opt": opt}
-    start = 0
-    if args.resume and ck.latest():
-        from repro.train import restore_tree
+        start = 0
+        if args.resume and ck.latest():
+            from repro.train import restore_tree
 
-        state, start = restore_tree(ck.latest(), state)
-        print(f"resumed from step {start}")
+            state, start = restore_tree(ck.latest(), state)
+            print(f"resumed from step {start}")
 
-    log = []
-    t0 = time.time()
+        log = []
+        t0 = time.time()
+        t_last = time.perf_counter()
 
-    def on_metrics(step, m):
-        loss = float(m["loss"])
-        log.append({"step": step, "loss": loss})
-        if step % 10 == 0 or step == args.steps:
-            print(f"step {step:5d} loss {loss:.4f} "
-                  f"({(time.time()-t0)/max(step-start,1):.2f}s/step)")
+        def on_metrics(step, m):
+            nonlocal t_last
+            loss = float(m["loss"])     # waits for the step's device work
+            now = time.perf_counter()
+            log.append({"step": step, "loss": loss, "wall_s": now - t_last})
+            t_last = now
+            if step % 10 == 0 or step == args.steps:
+                print(f"step {step:5d} loss {loss:.4f} "
+                      f"({(time.time()-t0)/max(step-start,1):.2f}s/step)")
 
-    state = sup.run(state, batch_for, args.steps, start_step=start,
-                    on_metrics=on_metrics)
-    ck.shutdown()
-    pipe.close()
-    if service is not None:
-        service.shutdown()
+        sup.run(state, batch_for, args.steps, start_step=start,
+                on_metrics=on_metrics)
     summary = pipe.ck  # ckio instance
-    print(json.dumps({
+    return {
         "final_loss": log[-1]["loss"] if log else None,
         "first_loss": log[0]["loss"] if log else None,
         "steps": sup.stats.steps_run,
         "failures": sup.stats.failures,
+        "log": log,
         "sched_tasks": summary.sched.stats,
         "ingest": pipe.ingest.summary(),
         "stream": pipe.stream.summary() if args.streaming else None,
@@ -297,7 +336,7 @@ def main() -> None:
                    if len(args.data) > 1 else None),
         "service": (service.metrics.summary() if service is not None
                     else None),
-    }, indent=2))
+    }
 
 
 if __name__ == "__main__":

@@ -21,9 +21,13 @@ from repro.train.optimizer import OptConfig, adamw_update
 
 
 def _split_microbatches(batch: Dict[str, jax.Array], nmb: int) -> Dict[str, jax.Array]:
+    """Microbatch ``m`` takes rows ``m, m + nmb, ...``: strided, so that a
+    batch sharded in contiguous row blocks over data-parallel devices gives
+    every microbatch rows on every device, and no device waits for another's
+    microbatch."""
     def r(x):
         assert x.shape[0] % nmb == 0, f"batch {x.shape[0]} % {nmb} != 0"
-        return x.reshape((nmb, x.shape[0] // nmb) + x.shape[1:])
+        return x.reshape((x.shape[0] // nmb, nmb) + x.shape[1:]).swapaxes(0, 1)
 
     return jax.tree.map(r, batch)
 

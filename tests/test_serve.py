@@ -55,10 +55,13 @@ VOCAB = 97
 
 
 def _shm_leftovers():
+    # This process's segments only (every name carries its creator's pid):
+    # test files running in parallel processes hold live segments too.
     d = "/dev/shm"
     if not os.path.isdir(d):
         return []
-    return [n for n in os.listdir(d) if n.startswith("ckio-")]
+    mine = f"-{os.getpid()}-"
+    return [n for n in os.listdir(d) if n.startswith("ckio-") and mine in n]
 
 
 @pytest.fixture(autouse=True)

@@ -135,6 +135,17 @@ def test_dryrun_subprocess_lowers_real_cell(tmp_path):
     assert rec["chips"] == 256
 
 
+def test_compile_cache_dir_honours_env_else_checkout(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.compile_cache_dir() == str(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    assert compile_cache.compile_cache_dir() == os.path.join(checkout,
+                                                             ".jax_cache")
+
+
 def test_collective_parser():
     from repro.launch.dryrun import collective_bytes_from_hlo
 
