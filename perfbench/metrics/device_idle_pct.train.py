@@ -1,0 +1,8 @@
+"""Device: the share of the traced training window in which no operation
+ran on the device, in %, averaged over the chips."""
+
+
+def read(r):
+    if r.trace is None or r.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - r.trace.busy_s / r.trace.window_s)
