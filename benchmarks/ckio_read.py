@@ -17,20 +17,16 @@ def ckio_read(
     network=None,
     pfs=None,
     timeout: float = 300.0,
-    piece_timing_every: int = 1,
 ) -> Tuple[int, Dict[str, float]]:
     """Full-file session read with ``num_clients`` over-decomposed consumers.
 
-    Returns (bytes_read, session-metrics summary). Benchmarks opt into
-    per-piece delivery timing (off by default on the hot path) so the
-    permutation-cost breakdown stays measurable."""
+    Returns (bytes_read, session-metrics summary)."""
     ck = CkIO(num_pes=num_pes, pes_per_node=pes_per_node)
     fh = ck.open_sync(path, FileOptions(
         num_readers=num_readers,
         splinter_bytes=splinter_bytes,
         network=network,
         delay_model=pfs.reader_delay_model() if pfs is not None else None,
-        piece_timing_every=piece_timing_every,
     ))
     sess = ck.start_read_session_sync(fh, fh.size, 0)
     per = fh.size // num_clients

@@ -5,13 +5,12 @@ dispatch cost, before/after the zero-copy rework.
   * ``use_preadv=False`` — the seed's ``os.pread`` allocate-then-copy into
     the arena (copy #1 + transient bytes alloc);
   * destination-buffer reads — per-piece memcpy arena→client buffer (copy #2);
-  * ``piece_timing_every=1`` — the seed timed every piece unconditionally;
   * ``prefault_arena=True`` — the seed's ``bytearray`` arena zero-filled the
     whole session on the start critical path.
 
 "After" is the new path: ``preadv`` straight into the arena (zero
 intermediate copies), borrowed-view delivery (zero delivery copies, proven
-via ``bytes_copied == 0``), coalesced pieces, sampled-off timing.
+via ``bytes_copied == 0``), coalesced pieces.
 
 Warm cache on purpose: with the file in DRAM the storage cost vanishes and
 the measured number is exactly the per-byte software overhead this PR
@@ -45,7 +44,6 @@ def drain_session(path: str, *, legacy: bool, num_clients: int = 64,
     opts = FileOptions(
         num_readers=NUM_READERS,
         splinter_bytes=SPLINTER,
-        piece_timing_every=1 if legacy else 0,
         prefault_arena=legacy,        # seed zero-filled the arena up front
     )
     fh = ck.open_sync(path, opts)

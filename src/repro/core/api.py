@@ -48,8 +48,6 @@ Tuning knobs (``FileOptions``)
 * ``splinter_bytes`` — unit of physical I/O / early fulfilment (§VI-C);
 * ``work_stealing`` — straggler mitigation between reader threads;
 * ``placement`` — reader→PE mapping policy (``core/placement.py``);
-* ``piece_timing_every`` — sample rate for per-piece delivery timing
-  (0 = off, keeping instrumentation off the hot path);
 * ``network`` — optional cross-node transfer model for locality studies.
 """
 from __future__ import annotations
@@ -57,6 +55,7 @@ from __future__ import annotations
 import threading
 from typing import Any, Callable, List, Optional, Union
 
+from repro.core import tracing
 from repro.core.director import Director
 from repro.core.futures import CkCallback, CkFuture
 from repro.core.migration import Client, LocationManager
@@ -399,9 +398,10 @@ class CkIO:
         return session.arrival_order
 
     def close_read_session_sync(self, session: Session, timeout: float = 60.0) -> None:
-        f: CkFuture = CkFuture()
-        self.close_read_session(session, f)
-        f.wait(self.sched, timeout=timeout)
+        with tracing.span("ckio.session.close", session=session.id):
+            f: CkFuture = CkFuture()
+            self.close_read_session(session, f)
+            f.wait(self.sched, timeout=timeout)
 
     def close_sync(self, file: FileHandle, timeout: float = 60.0) -> None:
         f: CkFuture = CkFuture()

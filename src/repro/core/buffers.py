@@ -31,6 +31,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.metrics import LocalityMetrics, SessionMetrics
 from repro.core.placement import Topology
 from repro.core.scheduler import TaskScheduler
@@ -106,8 +107,6 @@ class ReaderOptions:
     delay_model: Optional[Callable[[int, Splinter], float]] = None
     # optional cross-node transfer model (None = immediate hand-off)
     network: Optional["NetworkModel"] = None
-    # per-piece delivery timing sample rate (0 = off; N = every Nth piece)
-    piece_timing_every: int = 0
     # PE -> NUMA-domain model (core/placement.py). Enables domain-coalesced
     # pieces, cross-domain delivery accounting, and — with prefault_arena —
     # per-stripe first-touch on the owning reader's thread.
@@ -242,8 +241,9 @@ class BufferReaderSet:
         self.reader_pes = reader_pes[: plan.num_readers]
         self.opts = opts
         self.metrics = metrics or SessionMetrics()
-        if opts.piece_timing_every:
-            self.metrics.piece_timing_every = opts.piece_timing_every
+        # The read session this reader set serves (its spans carry it); the
+        # Director sets it before start().
+        self.session_id: Optional[int] = None
 
         self.locality = LocalityMetrics()
         # FileSet sessions: the handle resolves offsets to shard ids
@@ -546,13 +546,15 @@ class BufferReaderSet:
                 d = self.opts.delay_model(sp.reader, sp)
                 if d > 0:
                     time.sleep(d)
-            t0 = time.perf_counter()
             lo = sp.offset - self._base
             view = memoryview(self._arena)[lo : lo + sp.nbytes]
-            n = self.file.pread_into(sp.offset, view,
-                                     stats=self.metrics.recovery,
-                                     fault=self.opts.io_fault)
-            dt = time.perf_counter() - t0
+            with tracing.span("ckio.reader.read", session=self.session_id,
+                              reader=sp.reader, nbytes=sp.nbytes):
+                t0 = time.perf_counter()
+                n = self.file.pread_into(sp.offset, view,
+                                         stats=self.metrics.recovery,
+                                         fault=self.opts.io_fault)
+                dt = time.perf_counter() - t0
             if n != sp.nbytes and not self._cancelled:
                 raise IOError(
                     f"short read: wanted {sp.nbytes} at {sp.offset}, got {n}"
@@ -626,6 +628,10 @@ class BufferReaderSet:
             # visible to that waiter.
             self.metrics.record_inflight_hwm(eng.max_inflight)
             self.metrics.record_read(sp.reader, sp.nbytes, dt)
+            end = time.perf_counter_ns()
+            tracing.record("ckio.reader.read", end - int(dt * 1e9), end,
+                           session=self.session_id, reader=sp.reader,
+                           nbytes=sp.nbytes)
             if self._shard_of is not None:
                 self.metrics.record_shard_read(self._shard_of(sp.offset),
                                                sp.nbytes)
@@ -1111,6 +1117,11 @@ class ProcessReaderSet(BufferReaderSet):
         sp = Splinter(reader=ev.reader, index=ev.index,
                       offset=ev.offset, nbytes=ev.nbytes)
         self.metrics.record_read(ev.reader, ev.nbytes, ev.read_dt)
+        # The worker's own stamps: its read ended at ``t_arrival``.
+        tracing.record("ckio.reader.read",
+                       int((ev.t_arrival - ev.read_dt) * 1e9),
+                       int(ev.t_arrival * 1e9), session=self.session_id,
+                       reader=ev.reader, nbytes=ev.nbytes)
         if self._shard_of is not None:
             self.metrics.record_shard_read(self._shard_of(ev.offset),
                                            ev.nbytes)

@@ -15,6 +15,7 @@ import threading
 import warnings
 from typing import Callable, Dict, List, Optional
 
+from repro.core import tracing
 from repro.core.assembler import ReadAssembler
 from repro.core.buffers import BufferReaderSet, ProcessReaderSet
 from repro.core.futures import CkCallback
@@ -35,6 +36,10 @@ from repro.core.autotune import (
 )
 from repro.io.layout import plan_session
 from repro.io.posix import DEFAULT_ALIGN, PosixFile
+
+# Session ids are unique in the process, not only per Director, so that the
+# spans of one session (core/tracing.py) are told apart from another's.
+_session_ids = itertools.count()
 
 
 class Manager:
@@ -61,7 +66,6 @@ class Director:
             Manager(sched, pe) for pe in range(sched.num_pes)
         ]
         self._file_ids = itertools.count()
-        self._session_ids = itertools.count()
         self._lock = threading.Lock()
         self.files: Dict[int, FileHandle] = {}
         self.sessions: Dict[int, Session] = {}
@@ -189,6 +193,12 @@ class Director:
         num_readers = max(num_readers, len(hard_bounds) + 1)
 
         def do_start() -> None:
+            with tracing.span("ckio.session.start", nbytes=nbytes) as sp:
+                session = start()
+                sp.tag(session=session.id)
+            broadcast(session)
+
+        def start() -> Session:
             if sequenced:
                 # Global coordination (paper §III-C.1): serialize the greedy
                 # read kick-off of concurrent sessions on distinct files.
@@ -279,7 +289,9 @@ class Director:
                 # deadlock every future sequenced session start.
                 if sequenced:
                     self._sequence_lock.release()
+            return session
 
+        def broadcast(session: Session) -> None:
             # Broadcast to the Manager group; last ack fires `ready`.
             acks = {"n": 0}
             npes = self.sched.num_pes
@@ -372,7 +384,7 @@ class Director:
         resources released before the exception propagates (so a fallback
         retry starts clean)."""
         with self._lock:
-            sid = next(self._session_ids)
+            sid = next(_session_ids)
         readers = None
         try:
             if service is not None:
@@ -398,6 +410,7 @@ class Director:
             )
             with self._lock:
                 self.sessions[sid] = session
+            readers.session_id = sid
             # Greedy prefetch begins NOW — before any client request
             # exists.
             readers.start()

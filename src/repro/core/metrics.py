@@ -4,10 +4,8 @@ Everything the paper's evaluation plots (throughput, overlap fraction,
 permutation cost, cross-node traffic) is derived from these counters.
 Thread-safe; negligible overhead (integer adds under a lock).
 
-Per-piece *timing* (two ``perf_counter`` calls per delivered piece) is the
-one non-negligible probe, so it sits behind ``piece_timing_every``: 0 (the
-default) disables it entirely, N samples every Nth piece — delivery
-instrumentation stays off the hot path unless a benchmark opts in.
+Timings of the delivery hot path are spans of ``core/tracing.py``, which
+record only while a profiler trace is being taken.
 ``bytes_copied`` counts bytes physically memcpy'd into a client destination
 buffer; the borrowed-view path leaves it untouched, which is how benchmarks
 and tests *prove* zero-copy delivery rather than assume it.
@@ -19,6 +17,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
+
+from repro.core import tracing
 
 
 @dataclass
@@ -223,11 +223,7 @@ class SessionMetrics:
     # phantom transfer does not).
     cross_node_bytes: int = 0
     cross_node_view_bytes: int = 0
-    permute_time_s: float = 0.0
-    timed_pieces: int = 0             # pieces that contributed to permute_time_s
-    piece_timing_every: int = 0       # 0 = timing off; N = time every Nth piece
     requests: int = 0
-    request_latencies_s: List[float] = field(default_factory=list)
     # Fault-recovery observables (respawns, re-issued splinters, I/O
     # retries, …); travels the same Director observer path as the rest of
     # the session counters. Has its own lock.
@@ -254,7 +250,6 @@ class SessionMetrics:
     service_epoch: int = 0
     service_checkout_s: float = 0.0
     arena_recycled: bool = False
-    _piece_seq: int = 0               # sampling counter (racy by design)
 
     def session_started(self, nbytes: int, num_readers: int) -> None:
         with self.lock:
@@ -307,19 +302,10 @@ class SessionMetrics:
                 self.steals_from_reader.get(victim, 0) + 1
             )
 
-    def should_time_piece(self) -> bool:
-        """Cheap sampling decision — no lock; an off-by-one under contention
-        only shifts which piece gets sampled."""
-        if self.piece_timing_every <= 0:
-            return False
-        self._piece_seq += 1
-        return self._piece_seq % self.piece_timing_every == 0
-
     def record_piece(
         self,
         nbytes: int,
         cross_node: bool,
-        dt: Optional[float] = None,
         copied: int = 0,
         borrowed: bool = False,
     ) -> None:
@@ -335,14 +321,10 @@ class SessionMetrics:
                     self.cross_node_view_bytes += nbytes
                 else:
                     self.cross_node_bytes += nbytes
-            if dt is not None:
-                self.permute_time_s += dt
-                self.timed_pieces += 1
 
-    def record_request(self, latency_s: float) -> None:
+    def record_request(self) -> None:
         with self.lock:
             self.requests += 1
-            self.request_latencies_s.append(latency_s)
 
     def record_service_checkout(self, epoch: int, checkout_s: float,
                                 arena_recycled: bool) -> None:
@@ -389,8 +371,6 @@ class SessionMetrics:
             "bytes_copied": float(self.bytes_copied),
             "cross_node_bytes": float(self.cross_node_bytes),
             "cross_node_view_bytes": float(self.cross_node_view_bytes),
-            "permute_time_s": self.permute_time_s,
-            "timed_pieces": float(self.timed_pieces),
             "requests": float(self.requests),
             "imbalance": self.imbalance(),
             "shards_read": float(len(self.shard_bytes)),
@@ -891,6 +871,7 @@ class IngestMetrics:
             self.h2d_transfers += transfers
             self.h2d_bytes += staged_bytes
             self.host_permute_bytes += host_bytes
+        tracing.count("pipeline.h2d_transfers", transfers)
 
     def summary(self) -> Dict[str, float]:
         with self.lock:

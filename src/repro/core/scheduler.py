@@ -27,6 +27,8 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable, Deque, Dict, Iterable, List, Optional, Tuple
 
+from repro.core import tracing
+
 
 @dataclass
 class _Task:
@@ -190,8 +192,15 @@ class TaskScheduler:
 
         When no task is ready and the predicate is still false, park on the
         condition variable — I/O helper threads wake us by enqueueing
-        completions. Raises ``QuiescenceTimeout`` on deadline.
+        completions. Raises ``QuiescenceTimeout`` on deadline. Traced as
+        ``ckio.wait``: its time less that of the tasks it runs is the caller
+        parked with nothing to do.
         """
+        with tracing.span("ckio.wait"):
+            self._run_until(predicate, timeout)
+
+    def _run_until(self, predicate: Callable[[], bool],
+                   timeout: float) -> None:
         deadline = time.monotonic() + timeout
         while not predicate():
             if self.step():

@@ -361,6 +361,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core import CkIO, Client, FileOptions, Session, WorkerCrashed
+from repro.core import tracing
 from repro.core.buffers import SplinterEvent
 from repro.core.futures import CkCallback, CkFuture
 from repro.core.metrics import IngestMetrics, StreamMetrics
@@ -785,17 +786,18 @@ class CkIOPipeline:
             tokens = np.frombuffer(view, dtype=self.meta.dtype)
             if tokens.dtype == np.uint32:
                 tokens = tokens.view(np.int32)
-            t0 = time.perf_counter()
             self.stream.stage_inflight(ev.nbytes)
             try:
-                chunk = jax.device_put(tokens)
+                with tracing.span("pipeline.stage", nbytes=ev.nbytes):
+                    t0 = time.perf_counter()
+                    chunk = jax.device_put(tokens)
+                    t1 = time.perf_counter()
             except BaseException:
                 # A failed transfer never reaches _stage_outstanding, so
                 # its budget charge must be rolled back here or
                 # inflight_bytes stays inflated for the pipeline's life.
                 self.stream.stage_inflight(-ev.nbytes)
                 raise
-            t1 = time.perf_counter()
             if st.t_first_stage == 0.0:
                 st.t_first_stage = t0
             st.t_last_stage = t1
@@ -859,7 +861,8 @@ class CkIOPipeline:
                 sub = tokens[lo - tok0: hi - tok0]
                 self.stream.stage_inflight(nb)
                 try:
-                    chunk = jax.device_put(sub, dev)
+                    with tracing.span("pipeline.stage", nbytes=nb):
+                        chunk = jax.device_put(sub, dev)
                 except BaseException:
                     self.stream.stage_inflight(-nb)
                     raise
@@ -951,6 +954,10 @@ class CkIOPipeline:
         self.stream.stage_inflight(-sum(n for _, _, n in own))
 
     def _close_retired(self) -> None:
+        with tracing.span("pipeline.close_retired"):
+            self._close_retired_sessions()
+
+    def _close_retired_sessions(self) -> None:
         with self._lock:
             retired, self._retired = self._retired, []
             staged, self._staged = self._staged, []
@@ -983,9 +990,11 @@ class CkIOPipeline:
             raise IndexError(f"step {step} >= {self.num_steps}")
         self.start_step(step)  # no-op if already started
         buf = self._bufs[step]
-        buf.ready.wait(self.ck.sched, timeout=timeout)
+        with tracing.span("pipeline.wait_ready", step=step):
+            buf.ready.wait(self.ck.sched, timeout=timeout)
         # Launch the lookahead before handing the batch to the trainer.
-        self.start_step(step + self.prefetch_depth)
+        with tracing.span("pipeline.lookahead", step=step):
+            self.start_step(step + self.prefetch_depth)
         with self._lock:
             self._bufs.pop(step, None)
         return buf
@@ -1057,6 +1066,11 @@ class CkIOPipeline:
         call only ships the tail, concatenates on device, and runs the
         arrival-order gather — see "Streamed staging" in the module
         docstring."""
+        with tracing.span("pipeline.get_batch_device", step=step):
+            return self._batch_device(step, sharding, use_pallas, timeout)
+
+    def _batch_device(self, step: int, sharding, use_pallas: Optional[bool],
+                      timeout: float):
         import jax
 
         from repro.kernels import ops
@@ -1101,7 +1115,8 @@ class CkIOPipeline:
         valid_tokens = buf.nbytes // itemsize
         # The step's single host→device transfer (sharding=None → default
         # device placement).
-        staged = jax.device_put(tokens, sharding)
+        with tracing.span("pipeline.stage", nbytes=buf.nbytes):
+            staged = jax.device_put(tokens, sharding)
         if self.zero_copy:
             with self._lock:
                 # borrow_view in _window_tokens appended the session; the
@@ -1111,15 +1126,16 @@ class CkIOPipeline:
                     host_tokens=tokens,
                     host_view=view,
                 ))
-        inputs, labels = ops.device_ingest(
-            staged,
-            None,                       # arena view is file-order
-            global_batch=self.global_batch,
-            seq_len=self.seq_len,
-            valid_tokens=valid_tokens,
-            pad_id=self.pad_id,
-            use_pallas=use_pallas,
-        )
+        with tracing.span("pipeline.assemble"):
+            inputs, labels = ops.device_ingest(
+                staged,
+                None,                   # arena view is file-order
+                global_batch=self.global_batch,
+                seq_len=self.seq_len,
+                valid_tokens=valid_tokens,
+                pad_id=self.pad_id,
+                use_pallas=use_pallas,
+            )
         # Copy mode still pays the session→step-arena host copy before
         # staging; only the zero-copy path truly has 0 host bytes.
         self.ingest.record_device_step(
@@ -1161,10 +1177,11 @@ class CkIOPipeline:
             pos += nb
         if pos != abs_off + buf.nbytes:
             raise RuntimeError("streamed pieces do not cover the window")
-        inputs, labels = ops.ingest_chunks_window(
-            chunks, global_batch=self.global_batch, seq_len=self.seq_len,
-            valid_limit=valid_tokens, pad_id=self.pad_id,
-            use_pallas=use_pallas)
+        with tracing.span("pipeline.assemble"):
+            inputs, labels = ops.ingest_chunks_window(
+                chunks, global_batch=self.global_batch, seq_len=self.seq_len,
+                valid_limit=valid_tokens, pad_id=self.pad_id,
+                use_pallas=use_pallas)
         with self._lock:
             self._retired.append(sess)
             # Pin the chunk views + outputs until the next step: the
@@ -1281,8 +1298,10 @@ class CkIOPipeline:
             pos += nb
         if pos != buf.abs_off + buf.nbytes:
             raise RuntimeError("streamed pieces do not cover the window")
-        window = self._assemble_sharded_window(st.dev_pieces, valid_tokens)
-        inputs, labels = self._shift(window)
+        with tracing.span("pipeline.assemble"):
+            window = self._assemble_sharded_window(st.dev_pieces,
+                                                   valid_tokens)
+            inputs, labels = self._shift(window)
         self.ck.director.shards.record_window(
             buf.nbytes, self._addressable_window_bytes(valid_tokens))
         with self._lock:
@@ -1327,14 +1346,17 @@ class CkIOPipeline:
                 shards.record_cross_host((hi - lo) * itemsize)
                 continue
             if hi > lo:
-                chunk = jax.device_put(tokens[lo:hi], dev)
+                with tracing.span("pipeline.stage",
+                                  nbytes=(hi - lo) * itemsize):
+                    chunk = jax.device_put(tokens[lo:hi], dev)
                 dev_pieces[dev] = [(lo, chunk)]
                 shards.record_stage(str(dev), (hi - lo) * itemsize)
                 npieces += 1
         shards.record_window(
             buf.nbytes, self._addressable_window_bytes(valid_tokens))
-        window = self._assemble_sharded_window(dev_pieces, valid_tokens)
-        inputs, labels = self._shift(window)
+        with tracing.span("pipeline.assemble"):
+            window = self._assemble_sharded_window(dev_pieces, valid_tokens)
+            inputs, labels = self._shift(window)
         if self.zero_copy:
             with self._lock:
                 # borrow_view in _window_tokens appended the session; the

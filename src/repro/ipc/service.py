@@ -72,6 +72,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.buffers import ProcessReaderSet, ReaderOptions
 from repro.core.metrics import ServiceMetrics, SessionMetrics
 from repro.core.scheduler import TaskScheduler
@@ -679,6 +680,10 @@ class ReaderService:
             if wave.primary:
                 latency = time.monotonic() - wave.state.t_submit
                 self.metrics.record_checkout(latency)
+                end = time.perf_counter_ns()
+                tracing.record("service.admit", end - int(latency * 1e9),
+                               end, session=wave.state.set_.session_id,
+                               workers=len(wave.workers))
                 wave.state.set_.metrics.record_service_checkout(
                     wave.epoch, latency,
                     wave.state.set_.arena_recycled)

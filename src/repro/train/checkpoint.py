@@ -27,6 +27,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro.core import tracing
+
 MAGIC = b"CKPT-CKIO-v1\x00\x00\x00\x00"
 ALIGN = 4096
 
@@ -122,9 +124,18 @@ def restore_arrays(
                 ).reshape(e["shape"]).copy()
         return out, manifest
 
+    with tracing.span("checkpoint.restore_arrays",
+                      nbytes=manifest["total_bytes"]):
+        return _restore_ckio(path, manifest, num_readers, num_pes), manifest
+
+
+def _restore_ckio(path: str, manifest: Dict[str, Any],
+                  num_readers: Optional[int],
+                  num_pes: int) -> Dict[str, np.ndarray]:
     from repro.core import CkIO, FileOptions
     from repro.core.autotune import suggest_num_readers
 
+    data_off = manifest["data_offset"]
     ck = CkIO(num_pes=num_pes)
     total = manifest["total_bytes"]
     readers = num_readers or suggest_num_readers(total, num_pes, 1)
@@ -144,12 +155,15 @@ def restore_arrays(
         f.wait(ck.sched, timeout=600)
     ck.close_read_session_sync(sess)
     ck.close_sync(fh)
-    for e in manifest["leaves"]:
-        raw = bufs[e["name"]]
-        out[e["name"]] = np.frombuffer(
-            raw.tobytes(), dtype=np.dtype(e["dtype"])
-        ).reshape(e["shape"])
-    return out, manifest
+    out: Dict[str, np.ndarray] = {}
+    with tracing.span("checkpoint.unpack"):
+        for e in manifest["leaves"]:
+            raw = bufs[e["name"]]
+            out[e["name"]] = np.frombuffer(
+                raw.tobytes(), dtype=np.dtype(e["dtype"])
+            ).reshape(e["shape"])
+            tracing.count("checkpoint.unpack_bytes", raw.nbytes)
+    return out
 
 
 def restore_tree(path: str, like: Any, **kw) -> Tuple[Any, int]:

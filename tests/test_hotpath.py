@@ -295,22 +295,30 @@ def test_ready_deque_many_pes_fifo_and_fair():
     assert order[0][0] != order[1][0]
 
 
-def test_piece_timing_sampled_off_by_default(data_file):
+def test_piece_timing_sampled_off_by_default(data_file, tmp_path):
+    import jax
+
+    from repro.core import tracing
+
     path, _ = data_file
+    tracing.reset()
     ck = CkIO(num_pes=2)
     fh = ck.open_sync(path, FileOptions(num_readers=2))
     sess = ck.start_read_session_sync(fh, 100_000, 0)
     ck.read_sync(sess, 50_000, 0)
-    assert sess.metrics.timed_pieces == 0          # off the hot path
-    assert sess.metrics.permute_time_s == 0.0
+    assert tracing.snapshot().spans == []          # off the hot path
     ck.close_read_session_sync(sess)
-    # opt-in sampling
-    ck2 = CkIO(num_pes=2)
-    fh2 = ck2.open_sync(path, FileOptions(num_readers=2,
-                                          piece_timing_every=1))
-    sess2 = ck2.start_read_session_sync(fh2, 100_000, 0)
-    ck2.read_sync(sess2, 50_000, 0)
-    assert sess2.metrics.timed_pieces > 0
-    ck2.close_read_session_sync(sess2)
-    ck2.close_sync(fh2)
+    # timed while a profiler trace is being taken: one span per piece
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        sess2 = ck.start_read_session_sync(fh, 100_000, 0)
+        ck.read_sync(sess2, 50_000, 0)
+        ck.close_read_session_sync(sess2)
+    finally:
+        jax.profiler.stop_trace()
+    delivered = tracing.snapshot().named("ckio.deliver")
+    assert len(delivered) == sess2.metrics.pieces_served > 0
+    assert sum(s.ids["nbytes"] for s in delivered) == 50_000
+    assert all(s.ids["session"] == sess2.id and s.ns >= 0
+               for s in delivered)
     ck.close_sync(fh)
