@@ -8,6 +8,9 @@ of tasks" layout the paper targets. Restore therefore *is* a CkIO workload:
 one read session over the blob, one consumer client per leaf (over-
 decomposed), reader count tuned independently — measured in
 benchmarks/fig13_train_input.py alongside the training-ingest comparison.
+The CkIO restore copies nothing on the host: each leaf is a read-only NumPy
+view of its bytes in the one session arena, and holding any restored leaf
+keeps the whole arena alive.
 
 Saves are split-phase like everything else here: ``AsyncCheckpointer.save``
 snapshots device arrays to host and hands the serialization + write to a
@@ -73,16 +76,10 @@ def save_checkpoint(path: str, tree: Any, step: int) -> Dict[str, Any]:
         for e, a in zip(entries, arrays):
             f.seek(data_off + e["offset"])
             f.write(np.ascontiguousarray(a).tobytes())
-        # pad the tail to the aligned total so read sessions spanning
-        # [data_off, data_off+total_bytes) never cross EOF — but only when
-        # the aligned total extends past the last leaf's final byte (else
-        # the pad byte would clobber data)
-        end_data = data_off + (
-            entries[-1]["offset"] + entries[-1]["nbytes"] if entries else 0
-        )
-        if data_off + offset > end_data:
-            f.seek(data_off + offset - 1)
-            f.write(b"\x00")
+        # extend the file, zero-filled, to the aligned total so read
+        # sessions spanning [data_off, data_off+total_bytes) never cross EOF
+        # (every leaf ends at or before it, so no data byte is touched)
+        f.truncate(data_off + offset)
         f.flush()
         os.fsync(f.fileno())
     os.replace(tmp, path)
@@ -110,7 +107,12 @@ def restore_arrays(
     num_pes: int = 4,
 ) -> Tuple[Dict[str, np.ndarray], Dict[str, Any]]:
     """Read every leaf; CkIO path reads the blob through one session with one
-    over-decomposed consumer per leaf."""
+    over-decomposed consumer per leaf.
+
+    On the CkIO path each leaf is a read-only view of the session arena (the
+    zero-copy borrowed-view read), so every leaf shares that one buffer and
+    holding any of them keeps the whole arena alive. ``use_ckio=False``
+    gives each leaf a writable array of its own."""
     manifest = read_manifest(path)
     data_off = manifest["data_offset"]
     out: Dict[str, np.ndarray] = {}
@@ -141,28 +143,27 @@ def _restore_ckio(path: str, manifest: Dict[str, Any],
     readers = num_readers or suggest_num_readers(total, num_pes, 1)
     fh = ck.open_sync(path, FileOptions(num_readers=readers))
     sess = ck.start_read_session_sync(fh, total, data_off)
-    bufs: Dict[str, np.ndarray] = {}
     futs = []
     for i, e in enumerate(manifest["leaves"]):
-        arr = np.empty(e["nbytes"], dtype=np.uint8)
-        bufs[e["name"]] = arr
         client = ck.make_client(pe=i % num_pes)
         futs.append(
-            ck.read_future(sess, e["nbytes"], data_off + e["offset"],
-                           data=arr, client=client)
+            ck.read_view_future(sess, e["nbytes"], data_off + e["offset"],
+                                client=client)
         )
-    for f in futs:
-        f.wait(ck.sched, timeout=600)
+    msgs = [f.wait(ck.sched, timeout=600) for f in futs]
+    out: Dict[str, np.ndarray] = {}
+    # Each leaf is an array over its borrowed view of the arena, made before
+    # the session closes: the array's buffer export keeps the arena alive
+    # through close and for as long as any leaf is held.
+    with tracing.span("checkpoint.unpack"):
+        for e, msg in zip(manifest["leaves"], msgs):
+            out[e["name"]] = np.frombuffer(
+                msg.data, dtype=np.dtype(e["dtype"])
+            ).reshape(e["shape"])
+            tracing.count("checkpoint.view_bytes", e["nbytes"])
+        tracing.count("checkpoint.unpack_bytes", 0)     # nothing is copied
     ck.close_read_session_sync(sess)
     ck.close_sync(fh)
-    out: Dict[str, np.ndarray] = {}
-    with tracing.span("checkpoint.unpack"):
-        for e in manifest["leaves"]:
-            raw = bufs[e["name"]]
-            out[e["name"]] = np.frombuffer(
-                raw.tobytes(), dtype=np.dtype(e["dtype"])
-            ).reshape(e["shape"])
-            tracing.count("checkpoint.unpack_bytes", raw.nbytes)
     return out
 
 

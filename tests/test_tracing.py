@@ -246,8 +246,12 @@ def test_restore_is_traced_from_session_to_unpack(tmp_path):
     assert all(spans[s.parent].name == "ckio.wait" and under_outer(s)
                for s in delivered)
     assert sum(s.ids["nbytes"] for s in delivered) == leaf_bytes
-    assert snap.counters["ckio.bytes_copied"] == leaf_bytes
-    assert snap.counters["checkpoint.unpack_bytes"] == leaf_bytes
+    (unpack,) = snap.named("checkpoint.unpack")
+    assert spans[unpack.parent] is outer
+    # The leaves are views of the session arena: nothing is copied.
+    assert snap.counters.get("ckio.bytes_copied", 0) == 0
+    assert snap.counters["checkpoint.unpack_bytes"] == 0
+    assert snap.counters["checkpoint.view_bytes"] == leaf_bytes
 
 
 def test_restore_without_a_trace_records_nothing(tmp_path):

@@ -130,6 +130,108 @@ def test_elastic_restore_sharded(tmp_path):
     assert restored["w"].sharding == shardings["w"]
 
 
+LEAF_CASES = {
+    "float32": lambda: jnp.linspace(-3, 3, 96, dtype=jnp.float32).reshape(8, 12),
+    "bfloat16": lambda: jnp.linspace(-2, 2, 33).astype(jnp.bfloat16),
+    "int32": lambda: jnp.arange(-40, 40, 3, dtype=jnp.int32).reshape(3, 9),
+    "scalar": lambda: jnp.asarray(-7.25, jnp.float32),
+    "zero_size": lambda: jnp.zeros((0, 5), jnp.float32),
+}
+
+
+def assert_same_bits(saved, restored):
+    for x, y in zip(jax.tree.leaves(saved), jax.tree.leaves(restored)):
+        x = np.asarray(x)
+        assert (y.dtype, y.shape) == (x.dtype, x.shape)
+        assert np.asarray(y).tobytes() == x.tobytes()
+
+
+def arena_of(leaf):
+    """The buffer a CkIO-restored leaf is a view of: the object under the
+    memoryview at the end of its ``.base`` chain."""
+    base = leaf
+    while not isinstance(base, memoryview):
+        base = base.base
+    return base.obj
+
+
+@pytest.mark.parametrize("use_ckio", [False, True], ids=["plain", "ckio"])
+@pytest.mark.parametrize("case", list(LEAF_CASES))
+def test_restored_leaf_is_bit_identical(tmp_path, case, use_ckio):
+    # "leaf" sorts after "head", so the case's leaf is the last in the file
+    # (a zero-size last leaf must not cut the file short).
+    tree = {"head": jnp.arange(37, dtype=jnp.float32),
+            "leaf": LEAF_CASES[case]()}
+    path = str(tmp_path / "c.ckpt")
+    save_checkpoint(path, tree, step=5)
+    restored, step = restore_tree(path, tree, use_ckio=use_ckio,
+                                  num_readers=2, num_pes=2)
+    assert step == 5
+    assert_same_bits(tree, restored)
+
+
+def test_view_leaves_outlive_their_session_and_the_next_restore(tmp_path):
+    import gc
+
+    def state(seed):
+        k = jax.random.split(jax.random.PRNGKey(seed), 2)
+        return {"a": jax.random.normal(k[0], (256, 64)),
+                "b": jax.random.randint(k[1], (4096,), 0, 1 << 30)}
+
+    first, second = state(1), state(2)
+    save_checkpoint(str(tmp_path / "1.ckpt"), first, step=1)
+    save_checkpoint(str(tmp_path / "2.ckpt"), second, step=2)
+    kept, _ = restore_tree(str(tmp_path / "1.ckpt"), first,
+                           num_readers=2, num_pes=2)
+    gc.collect()                    # the session and its readers are gone
+    assert_same_bits(first, kept)
+    # A second restore of the same size in the same process: were the first
+    # arena released or recycled under the kept leaves, they would change.
+    again, _ = restore_tree(str(tmp_path / "2.ckpt"), second,
+                            num_readers=2, num_pes=2)
+    assert_same_bits(second, again)
+    assert_same_bits(first, kept)
+    assert not np.shares_memory(kept["a"], again["a"])
+    del again
+    gc.collect()
+    assert_same_bits(first, kept)
+
+
+def test_ckio_leaves_are_read_only_views_of_one_arena(tmp_path):
+    tree = {name: make() for name, make in LEAF_CASES.items()}
+    path = str(tmp_path / "v.ckpt")
+    save_checkpoint(path, tree, step=3)
+    restored, _ = restore_tree(path, tree, num_readers=2, num_pes=2)
+    leaves = jax.tree.leaves(restored)
+    assert not any(leaf.flags.writeable for leaf in leaves)
+    with pytest.raises(ValueError):
+        restored["float32"][0, 0] = 1.0
+    arena = arena_of(leaves[0])
+    assert all(arena_of(leaf) is arena for leaf in leaves)
+    assert all(np.shares_memory(leaf, arena) for leaf in leaves if leaf.size)
+    # The plain path gives each leaf a writable array of its own.
+    plain, _ = restore_tree(path, tree, use_ckio=False)
+    assert all(leaf.flags.writeable for leaf in jax.tree.leaves(plain))
+
+
+def test_restore_sharded_of_view_leaves_on_one_device_mesh(tmp_path):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.train import restore_sharded
+
+    mesh = jax.make_mesh((1,), ("data",))
+    tree = {name: make() for name, make in LEAF_CASES.items()}
+    path = str(tmp_path / "s.ckpt")
+    save_checkpoint(path, tree, step=9)
+    shardings = {name: NamedSharding(mesh, P()) for name in tree}
+    shardings["float32"] = NamedSharding(mesh, P("data", None))
+    restored, step = restore_sharded(path, tree, shardings,
+                                     num_readers=2, num_pes=2)
+    assert step == 9
+    assert_same_bits(tree, restored)
+    for name, leaf in restored.items():
+        assert leaf.sharding == shardings[name]
+
+
 def test_supervisor_recovers_from_faults(tmp_path):
     cfg = smoke_config(get_config("qwen2-vl-2b"))
     model = build_model(cfg)
