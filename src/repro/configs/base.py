@@ -19,6 +19,7 @@ ATTN = "attn"            # global causal (or bidirectional in encoders)
 ATTN_LOCAL = "attn_local"  # sliding-window causal
 RGLRU = "rglru"          # Griffin recurrent block
 MAMBA = "mamba"          # Mamba-1 selective SSM block (no separate FFN)
+MLA = "mla"              # DeepSeek-V2/V3 multi-head latent attention
 
 # FFN kinds
 DENSE = "dense"
@@ -33,7 +34,7 @@ class LayerSpec:
     window: int = 0          # >0 for attn_local
 
     def __post_init__(self):
-        assert self.mixer in (ATTN, ATTN_LOCAL, RGLRU, MAMBA), self.mixer
+        assert self.mixer in (ATTN, ATTN_LOCAL, RGLRU, MAMBA, MLA), self.mixer
         assert self.ffn in (DENSE, MOE, NONE), self.ffn
 
 
@@ -69,18 +70,42 @@ class ModelConfig:
     vocab_size: int
     head_dim: int = 0                # 0 -> d_model // num_heads
 
-    # layer schedule: pattern repeated, remainder unrolled
+    # layer schedule: leading dense layers (DeepSeek-V3's
+    # first_k_dense_replace, the pattern's mixer with a dense FFN), then the
+    # pattern repeated, remainder unrolled
     block_pattern: Tuple[LayerSpec, ...] = (LayerSpec(),)
+    first_dense_layers: int = 0
 
     # MoE
     num_experts: int = 0
     num_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # softmax routing (sigmoid drops none)
     router_aux_coef: float = 0.01
     expert_pad: int = 0          # physical padding to a multiple of the EP axis
                                  # (padded experts are masked out of routing)
+    # router scoring: "softmax" (top-k of the probabilities, capacity
+    # dispatch, Switch aux loss) or "sigmoid" (DeepSeek-V3 noaux_tc: top-k of
+    # sigmoid scores plus a correction bias that has no gradient; chosen
+    # scores normalised and scaled by routed_scaling; dropless dispatch;
+    # sequence-wise balance loss)
+    router_score: str = "softmax"
+    routed_scaling: float = 1.0
+    bias_update_rate: float = 0.0    # gamma: b_i += gamma * sign(mean load - load_i)
+    # the experts this layer holds (expert parallelism): experts
+    # [first_expert, first_expert + experts_held); 0 holds all. The router
+    # stays at num_experts.
+    experts_held: int = 0
+    first_expert: int = 0
+
+    # MLA (no query compression): latent KV of kv_lora_rank plus one shared
+    # rotary key of qk_rope_head_dim; per head qk_nope + qk_rope for q and k,
+    # v_head_dim for v
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # positional
     rope_theta: float = 10_000.0
@@ -145,6 +170,16 @@ class ModelConfig:
         return self.head_dim or (self.d_model // max(self.num_heads, 1))
 
     @property
+    def rope_dim(self) -> int:
+        """Dimensions each rotary position rotates: MLA's rope part, else
+        the whole head."""
+        return self.qk_rope_head_dim or self.resolved_head_dim
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
     def is_encdec(self) -> bool:
         return self.encoder_layers > 0
 
@@ -152,16 +187,24 @@ class ModelConfig:
     def attention_free(self) -> bool:
         return all(s.mixer in (MAMBA, RGLRU) for s in self.block_pattern)
 
+    def lead_layers(self) -> Tuple[LayerSpec, ...]:
+        """The leading dense layers, unrolled before the scanned blocks."""
+        spec = LayerSpec(mixer=self.block_pattern[0].mixer, ffn=DENSE)
+        return (spec,) * self.first_dense_layers
+
     def layer_schedule(self) -> List[LayerSpec]:
-        """Full per-layer schedule (pattern cycled to num_layers)."""
+        """Full per-layer schedule (lead layers, then the pattern cycled to
+        num_layers)."""
         p = self.block_pattern
-        return [p[i % len(p)] for i in range(self.num_layers)]
+        rest = self.num_layers - self.first_dense_layers
+        return list(self.lead_layers()) + [p[i % len(p)] for i in range(rest)]
 
     def scan_split(self) -> Tuple[Tuple[LayerSpec, ...], int, Tuple[LayerSpec, ...]]:
-        """(block_pattern, num_full_blocks, tail_layers)."""
+        """(block_pattern, num_full_blocks, tail_layers), after the lead."""
         p = self.block_pattern
-        nb = self.num_layers // len(p)
-        tail = tuple(self.layer_schedule()[nb * len(p):])
+        nb = (self.num_layers - self.first_dense_layers) // len(p)
+        tail = tuple(self.layer_schedule()[self.first_dense_layers
+                                           + nb * len(p):])
         return p, nb, tail
 
     def replace(self, **kw) -> "ModelConfig":
@@ -177,6 +220,12 @@ class ModelConfig:
 
         def attn_params() -> int:
             return d * H * hd + 2 * d * K * hd + H * hd * d
+
+        def mla_params() -> int:
+            r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+            nope, v = self.qk_nope_head_dim, self.v_head_dim
+            return (d * H * (nope + rope) + d * (r + rope) + r
+                    + r * H * (nope + v) + H * v * d)
 
         def dense_ffn(ff: int) -> int:
             if self.act in ("silu", "gelu_glu"):
@@ -209,6 +258,8 @@ class ModelConfig:
         for spec in self.layer_schedule():
             if spec.mixer in (ATTN, ATTN_LOCAL):
                 total += attn_params(); active += attn_params()
+            elif spec.mixer == MLA:
+                total += mla_params(); active += mla_params()
             elif spec.mixer == MAMBA:
                 total += mamba_params(); active += mamba_params()
             elif spec.mixer == RGLRU:
@@ -217,9 +268,11 @@ class ModelConfig:
                 total += dense_ffn(self.d_ff); active += dense_ffn(self.d_ff)
             elif spec.ffn == MOE:
                 per_expert = dense_ffn(self.moe_d_ff)
-                total += self.num_experts * per_expert
+                total += self.held_experts * per_expert
                 total += self.num_shared_experts * per_expert
                 total += d * self.num_experts            # router
+                if self.router_score == "sigmoid":
+                    total += self.num_experts            # correction bias
                 active += self.top_k * per_expert
                 active += self.num_shared_experts * per_expert
                 active += d * self.num_experts

@@ -14,6 +14,7 @@ from repro.configs.base import ModelConfig, SHAPES, SHAPES_BY_NAME, ShapeConfig
 from repro.configs.codeqwen1_5_7b import CONFIG as _codeqwen
 from repro.configs.falcon_mamba_7b import CONFIG as _falcon_mamba
 from repro.configs.gemma3_27b import CONFIG as _gemma3
+from repro.configs.moonlight_16b_a3b import CONFIG as _moonlight
 from repro.configs.olmoe_1b_7b import CONFIG as _olmoe
 from repro.configs.phi3_medium_14b import CONFIG as _phi3
 from repro.configs.phi4_mini_3_8b import CONFIG as _phi4
@@ -35,6 +36,7 @@ ARCHS: Dict[str, ModelConfig] = {
         _whisper,
         _falcon_mamba,
         _recurrentgemma,
+        _moonlight,
     )
 }
 
@@ -66,10 +68,11 @@ def cells(include_long_for_all: bool = False):
 
 
 def smoke_config(cfg: ModelConfig) -> ModelConfig:
-    """Reduced same-family config: 2 scan blocks + original tail remainder."""
+    """Reduced same-family config: the lead layers, 2 scan blocks and the
+    original tail remainder."""
     pattern = cfg.block_pattern
-    tail_len = cfg.num_layers % len(pattern)
-    num_layers = 2 * len(pattern) + tail_len
+    tail_len = (cfg.num_layers - cfg.first_dense_layers) % len(pattern)
+    num_layers = cfg.first_dense_layers + 2 * len(pattern) + tail_len
     hd = 16
     heads = max(2, min(4, cfg.num_heads or 2))
     kv = 1 if cfg.num_kv_heads <= 1 else 2
@@ -89,7 +92,11 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
     )
     if cfg.num_experts:
         kw.update(num_experts=8, top_k=min(cfg.top_k, 2), moe_d_ff=32,
-                  num_shared_experts=min(cfg.num_shared_experts, 2))
+                  num_shared_experts=min(cfg.num_shared_experts, 2),
+                  experts_held=0, first_expert=0)
+    if cfg.kv_lora_rank:
+        kw.update(head_dim=24, kv_lora_rank=32, qk_nope_head_dim=16,
+                  qk_rope_head_dim=8, v_head_dim=16)
     if cfg.ssm_state:
         kw.update(d_inner=128, ssm_state=4, dt_rank=8, ssm_chunk=16)
     if cfg.lru_width:

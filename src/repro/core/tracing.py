@@ -119,6 +119,11 @@ class _Off:
 _OFF = _Off()
 
 
+def active() -> bool:
+    """Whether spans and counters are being recorded (a trace is taken)."""
+    return _enabled()
+
+
 def span(name: str, **ids):
     """Context manager timing the region it encloses (see module doc)."""
     if not _enabled():

@@ -95,6 +95,13 @@ def param_leaf_spec(path: str, shape: Tuple[int, ...], mesh) -> P:
             return spec(h, None)
         return spec(None, _maybe(trail[1], "model", m))
 
+    # MLA: the latent projection is split into c and k_pe, keep it whole;
+    # the latent's up-projection is per head
+    if name == "wkv_a":
+        return spec(None, None)
+    if name == "wkv_b":
+        return spec(None, _maybe(trail[1], "model", m), None)
+
     # MoE experts (3-D) before dense GLU (2-D)
     if name in ("gate", "up", "down") and nd == 3:
         return spec(_maybe(trail[0], "model", m), None, None)
@@ -106,6 +113,8 @@ def param_leaf_spec(path: str, shape: Tuple[int, ...], mesh) -> P:
         return spec(_maybe(trail[0], "model", m))
     if name == "router":
         return spec(None, None)
+    if name == "router_bias":
+        return spec(None)
 
     # mamba
     if name == "in_proj":

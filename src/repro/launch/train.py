@@ -32,6 +32,7 @@ from repro.train import (
     AsyncCheckpointer,
     OptConfig,
     StepSupervisor,
+    count_step,
     init_opt_state,
     make_train_step,
 )
@@ -311,6 +312,7 @@ def run(
         def on_metrics(step, m):
             nonlocal t_last
             loss = float(m["loss"])     # waits for the step's device work
+            count_step(m)
             now = time.perf_counter()
             log.append({"step": step, "loss": loss, "wall_s": now - t_last})
             t_last = now

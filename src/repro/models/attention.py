@@ -120,7 +120,7 @@ def _sdpa(
         scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
-    return out.reshape(B, Sq, H, hd)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 def causal_window_mask(sq: int, sk: int, window: int, offset: int = 0) -> jax.Array:
@@ -155,7 +155,7 @@ def _chunked_sdpa(q, k, v, *, causal: bool, window: int, softcap: float,
         return _sdpa(qs, k, v, mask=m[None, None, None], softcap=softcap)
 
     out = jax.lax.map(one_chunk, jnp.arange(nc))       # (nc, B, qc, H, hd)
-    return jnp.moveaxis(out, 0, 1).reshape(B, S, H, hd)
+    return jnp.moveaxis(out, 0, 1).reshape(B, S, H, v.shape[-1])
 
 
 def attention_train(

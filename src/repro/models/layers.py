@@ -207,6 +207,18 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
+def apply_rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array
+                           ) -> jax.Array:
+    """x: (B, S, H, hd); cos/sin: (B, S, hd//2). DeepSeek-V3's layout: pair
+    i is (x[2i], x[2i+1]). The result holds the rotated even members, then
+    the odd ones; a dot product of two vectors rotated alike does not depend
+    on that order."""
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    c = cos[:, :, None, :].astype(x.dtype)
+    s = sin[:, :, None, :].astype(x.dtype)
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+
+
 # -- loss --------------------------------------------------------------------------
 def softmax_xent(
     logits: jax.Array,      # (B, S, V) — possibly vocab-sharded

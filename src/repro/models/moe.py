@@ -10,6 +10,14 @@ Dispatch strategy (TPU/SPMD-native, flop-sane):
   gathers as the EP collectives. Dominant FLOPs = capacity_factor × ideal
   active FLOPs (vs the T² blow-up of naive one-hot dispatch einsums — see
   EXPERIMENTS.md §Perf for the measured comparison).
+
+``moe_apply_dropless`` is DeepSeek-V3's layer (arXiv:2412.19437 §2.1.2) as
+one expert-parallel share: sigmoid scores over every expert, top-k chosen
+on the scores plus a correction bias that has no gradient, the chosen
+scores normalised and scaled; the layer holds experts
+``[first, first + held)`` and computes their part of the result for every
+row routed to them (no capacity, no dropped token) with a grouped matmul
+whose work follows the rows routed, plus the shared experts.
 """
 from __future__ import annotations
 
@@ -22,15 +30,22 @@ from repro.models.layers import MODEL_AXIS, fan_in_init, shard_act
 
 
 def moe_init(key, d: int, num_experts: int, moe_ff: int, num_shared: int,
-             dtype, expert_pad: int = 0) -> dict:
+             dtype, expert_pad: int = 0, held: int = 0,
+             router_bias: bool = False) -> dict:
+    """``held`` experts' weights (0: all of them) beside a router over all
+    ``num_experts + expert_pad``; ``router_bias`` adds the correction bias
+    of sigmoid routing (zero)."""
     ks = jax.random.split(key, 5)
     ep = num_experts + expert_pad    # physical experts (EP divisibility)
+    n = held or ep
     p = {
         "router": fan_in_init(ks[0], (d, ep), d, dtype),
-        "gate": fan_in_init(ks[1], (ep, d, moe_ff), d, dtype),
-        "up": fan_in_init(ks[2], (ep, d, moe_ff), d, dtype),
-        "down": fan_in_init(ks[3], (ep, moe_ff, d), moe_ff, dtype),
+        "gate": fan_in_init(ks[1], (n, d, moe_ff), d, dtype),
+        "up": fan_in_init(ks[2], (n, d, moe_ff), d, dtype),
+        "down": fan_in_init(ks[3], (n, moe_ff, d), moe_ff, dtype),
     }
+    if router_bias:
+        p["router_bias"] = jnp.zeros((ep,), dtype=dtype)
     if num_shared > 0:
         ff_sh = num_shared * moe_ff
         kg, ku, kd = jax.random.split(ks[4], 3)
@@ -158,10 +173,114 @@ def moe_apply(
 
     # shared experts (always-on dense path, Qwen-MoE style)
     if "shared_gate" in params:
-        sg = x @ params["shared_gate"].astype(dtype)
-        su = x @ params["shared_up"].astype(dtype)
-        sh = jax.nn.silu(sg) * su
-        sh = shard_act(sh, "batch", None, MODEL_AXIS)
-        out = out + sh @ params["shared_down"].astype(dtype)
+        out = out + shared_experts(params, x, dtype)
 
     return out.astype(x.dtype), aux
+
+
+def shared_experts(params: dict, x: jax.Array, dtype) -> jax.Array:
+    sg = x @ params["shared_gate"].astype(dtype)
+    su = x @ params["shared_up"].astype(dtype)
+    sh = jax.nn.silu(sg) * su
+    sh = shard_act(sh, "batch", None, MODEL_AXIS)
+    return sh @ params["shared_down"].astype(dtype)
+
+
+def sigmoid_route(h: jax.Array, router: jax.Array, bias: jax.Array,
+                  top_k: int, scaling: float
+                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """DeepSeek-V3 noaux_tc routing with one group, in float32.
+
+    h: (T, d). Returns (chosen experts (T, k) int32, their weights (T, k):
+    the chosen scores over their sum times ``scaling``, scores (T, E))."""
+    logits = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    weight = chosen / (jnp.sum(chosen, -1, keepdims=True) + 1e-20) * scaling
+    return idx, weight, scores
+
+
+def sequence_balance_loss(scores: jax.Array, idx: jax.Array) -> jax.Array:
+    """DeepSeek-V3's sequence-wise balance loss before its weight alpha:
+    per sequence sum_i f_i P_i, with f_i = E / (k S) x (tokens choosing i)
+    and P_i the mean over the sequence of i's score over the token's score
+    sum; the mean over sequences. scores (B, S, E), idx (B, S, k)."""
+    B, S, E = scores.shape
+    k = idx.shape[-1]
+    chosen = jnp.zeros((B, E), jnp.float32).at[
+        jnp.arange(B)[:, None], idx.reshape(B, S * k)].add(1.0)
+    f = chosen * (E / (k * S))
+    p = jnp.mean(scores / jnp.sum(scores, -1, keepdims=True), axis=1)
+    return jnp.mean(jnp.sum(f * p, axis=-1))
+
+
+def held_expert_rows(idx: jax.Array, first: int, held: int):
+    """Dispatch plan of the held experts: the (token, choice) pairs routed to
+    experts ``[first, first + held)``, sorted by expert, in a buffer of the
+    most such pairs there can be (T x min(k, held)).
+
+    Returns (token of each buffer row (M,), the flat (token, choice) index
+    of each row (M,), which rows hold a routed pair (M,) bool, rows per held
+    expert (held,) int32)."""
+    T, k = idx.shape
+    local = idx.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    M = T * min(k, held)
+    pair = order[:M].astype(jnp.int32)
+    valid = jnp.arange(M) < jnp.sum(sizes)
+    return pair // k, pair, valid, sizes
+
+
+def expert_ffn(xs: jax.Array, sizes: jax.Array, params: dict, dtype
+               ) -> jax.Array:
+    """SwiGLU of each held expert on its rows of ``xs`` (sorted by expert,
+    ``sizes`` rows each): a grouped matmul whose work follows ``sizes``."""
+    g = jax.lax.ragged_dot(xs, params["gate"].astype(dtype), sizes)
+    u = jax.lax.ragged_dot(xs, params["up"].astype(dtype), sizes)
+    return jax.lax.ragged_dot(jax.nn.silu(g) * u,
+                              params["down"].astype(dtype), sizes)
+
+
+def moe_apply_dropless(
+    params: dict,
+    x: jax.Array,               # (B, S, d)
+    *,
+    top_k: int,
+    scaling: float,
+    first: int,
+    dtype,
+) -> Tuple[jax.Array, jax.Array, dict]:
+    """Returns (output (B, S, d), balance loss, stats): ``stats`` holds each
+    expert's load (tokens that chose it, every expert, float32), the rows
+    the held experts computed and the rows of the busiest held expert."""
+    B, S, d = x.shape
+    held = params["gate"].shape[0]
+    with jax.named_scope("moe.route"):
+        idx, weight, scores = sigmoid_route(
+            x.reshape(B * S, d), params["router"], params["router_bias"],
+            top_k, scaling)
+        E = scores.shape[-1]
+        aux = sequence_balance_loss(scores.reshape(B, S, E),
+                                    idx.reshape(B, S, top_k))
+        load = jnp.zeros((E,), jnp.float32).at[idx.reshape(-1)].add(1.0)
+        tok, pair, valid, sizes = held_expert_rows(idx, first, held)
+    with jax.named_scope("moe.experts"):
+        xf = x.reshape(B * S, d)
+        # Rows past the routed ones are outside every group: zero them, and
+        # their results, so that nothing the grouped matmul leaves there
+        # reaches the output or the gradient.
+        xs = jnp.where(valid[:, None], xf[tok], 0).astype(dtype)
+        ys = jnp.where(valid[:, None], expert_ffn(xs, sizes, params, dtype), 0)
+    with jax.named_scope("moe.combine"):
+        w = jnp.where(valid, weight.reshape(-1)[pair], 0.0)
+        out = jnp.zeros((B * S, d), ys.dtype).at[tok].add(
+            ys * w[:, None].astype(ys.dtype))
+        out = out.reshape(B, S, d)
+        if "shared_gate" in params:
+            out = out + shared_experts(params, x, dtype)
+    stats = {"load": load, "rows": jnp.sum(sizes), "rows_max": jnp.max(sizes)}
+    return out.astype(x.dtype), aux, stats

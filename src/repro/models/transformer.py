@@ -15,7 +15,17 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ATTN, ATTN_LOCAL, DENSE, MAMBA, MOE, NONE, RGLRU, ModelConfig
+from repro.configs.base import (
+    ATTN,
+    ATTN_LOCAL,
+    DENSE,
+    MAMBA,
+    MLA,
+    MOE,
+    NONE,
+    RGLRU,
+    ModelConfig,
+)
 from repro.models import attention as attn_mod
 from repro.models.attention import KVCache, attn_init, init_cache
 from repro.models.layers import (
@@ -30,7 +40,8 @@ from repro.models.layers import (
     softmax_xent,
     unembed_logits,
 )
-from repro.models.moe import moe_apply, moe_init
+from repro.models.mla import init_mla_cache, mla_decode, mla_init, mla_train
+from repro.models.moe import moe_apply, moe_apply_dropless, moe_init
 from repro.models.rglru import (
     RGLRUState,
     rglru_apply,
@@ -69,6 +80,11 @@ def init_layer(key, cfg: ModelConfig, spec) -> Dict[str, Any]:
             pd, bias=cfg.attn_bias, qk_norm=cfg.qk_norm,
             phys_heads=cfg.num_heads_phys, phys_kv=cfg.num_kv_heads_phys,
         )
+    elif spec.mixer == MLA:
+        p["mixer"] = mla_init(
+            ks[0], d, cfg.num_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+            cfg.qk_rope_head_dim, cfg.v_head_dim, pd,
+        )
     elif spec.mixer == MAMBA:
         p["mixer"] = mamba_init(
             ks[0], d, cfg.d_inner, cfg.ssm_state, cfg.dt_rank, cfg.conv_width, pd
@@ -83,6 +99,8 @@ def init_layer(key, cfg: ModelConfig, spec) -> Dict[str, Any]:
             p["ffn"] = moe_init(
                 ks[1], d, cfg.num_experts, cfg.moe_d_ff,
                 cfg.num_shared_experts, pd, expert_pad=cfg.expert_pad,
+                held=cfg.experts_held,
+                router_bias=cfg.router_score == "sigmoid",
             )
     return p
 
@@ -97,12 +115,16 @@ def init_block(key, cfg: ModelConfig) -> Dict[str, Any]:
 def init_model(key, cfg: ModelConfig) -> Dict[str, Any]:
     pd = _pdtype(cfg)
     pattern, nb, tail = cfg.scan_split()
-    n_keys = 2 + nb + len(tail) + (0 if cfg.tie_embeddings else 1)
+    lead = cfg.lead_layers()
+    n_keys = 2 + nb + len(tail) + (0 if cfg.tie_embeddings else 1) + len(lead)
     ks = jax.random.split(key, n_keys)
     params: Dict[str, Any] = {
         "embed": embed_init(ks[0], cfg.vocab_size, cfg.d_model, pd),
         "final_norm": rmsnorm_init(cfg.d_model, pd),
     }
+    if lead:
+        params["lead"] = [init_layer(ks[n_keys - len(lead) + i], cfg, spec)
+                          for i, spec in enumerate(lead)]
     if nb > 0:
         params["blocks"] = jax.vmap(lambda k: init_block(k, cfg))(
             jnp.stack(ks[2 : 2 + nb])
@@ -114,7 +136,8 @@ def init_model(key, cfg: ModelConfig) -> Dict[str, Any]:
         from repro.models.layers import fan_in_init
 
         params["lm_head"] = {
-            "w": fan_in_init(ks[-1], (cfg.d_model, cfg.vocab_size), cfg.d_model, pd)
+            "w": fan_in_init(ks[2 + nb + len(tail)],
+                             (cfg.d_model, cfg.vocab_size), cfg.d_model, pd)
         }
     return params
 
@@ -122,12 +145,35 @@ def init_model(key, cfg: ModelConfig) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 # train / prefill forward
 # ---------------------------------------------------------------------------
-def apply_layer_train(params, spec, cfg: ModelConfig, x, cos, sin
-                      ) -> Tuple[jax.Array, jax.Array]:
+def _ffn(params, spec, cfg: ModelConfig, h):
+    """The layer's FFN on ``h``: (output, aux loss, MoE stats or None)."""
+    dt = _dtype(cfg)
+    if spec.ffn == DENSE:
+        return mlp_apply(params, h, cfg.act, dt), jnp.zeros((), jnp.float32), None
+    if cfg.router_score == "sigmoid":
+        return moe_apply_dropless(
+            params, h, top_k=cfg.top_k, scaling=cfg.routed_scaling,
+            first=cfg.first_expert, dtype=dt,
+        )
+    f, aux = moe_apply(
+        params, h, top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
+        dtype=dt, num_real_experts=cfg.num_experts,
+    )
+    return f, aux, None
+
+
+def apply_layer_train(params, spec, cfg: ModelConfig, x, cos, sin):
+    """One layer: (output, aux loss, the MoE layer's stats or None)."""
     dt = _dtype(cfg)
     eps = cfg.norm_eps
     h = rmsnorm(params["norm1"], x, eps)
-    if spec.mixer in (ATTN, ATTN_LOCAL):
+    if spec.mixer == MLA:
+        m = mla_train(
+            params["mixer"], h, cos, sin, rank=cfg.kv_lora_rank,
+            nope=cfg.qk_nope_head_dim, dtype=dt, eps=eps,
+            q_chunk=cfg.attn_q_chunk,
+        )
+    elif spec.mixer in (ATTN, ATTN_LOCAL):
         m = attn_mod.attention_train(
             params["mixer"], h, cos, sin, dtype=dt, eps=eps, causal=True,
             window=spec.window, softcap=cfg.attn_logit_softcap,
@@ -142,19 +188,13 @@ def apply_layer_train(params, spec, cfg: ModelConfig, x, cos, sin
         raise ValueError(spec.mixer)
     x = x + m
     aux = jnp.zeros((), jnp.float32)
+    stats = None
     if spec.ffn != NONE:
         h = rmsnorm(params["norm2"], x, eps)
-        if spec.ffn == DENSE:
-            f = mlp_apply(params["ffn"], h, cfg.act, dt)
-        else:
-            f, aux = moe_apply(
-                params["ffn"], h, top_k=cfg.top_k,
-                capacity_factor=cfg.capacity_factor, dtype=dt,
-                num_real_experts=cfg.num_experts,
-            )
+        f, aux, stats = _ffn(params["ffn"], spec, cfg, h)
         x = x + f
     x = shard_act(x, "batch", None, None)
-    return x, aux
+    return x, aux, stats
 
 
 def _remat(fn, policy: str):
@@ -170,31 +210,68 @@ def _remat(fn, policy: str):
 
 
 def forward_backbone(params, cfg: ModelConfig, x, cos, sin
-                     ) -> Tuple[jax.Array, jax.Array]:
+                     ) -> Tuple[jax.Array, jax.Array, Optional[dict]]:
+    """(final hidden states, summed aux loss, MoE stats or None). The stats
+    of the dropless MoE layers: ``load``, each expert's tokens, laid out as
+    the layers' ``router_bias`` leaves (``blocks``: ``l<i>`` stacked over
+    blocks; ``tail``: by layer index), and ``rows``, ``rows_max`` and
+    ``groups``, summed over the layers."""
     pattern, nb, tail = cfg.scan_split()
     aux_total = jnp.zeros((), jnp.float32)
+    loads: Dict[str, Any] = {}
+    per_layer = []
 
+    for i, spec in enumerate(cfg.lead_layers()):
+        lead_fn = _remat(
+            lambda p, h, spec=spec: apply_layer_train(p, spec, cfg, h, cos,
+                                                      sin)[:2],
+            cfg.remat_policy)
+        x, a = lead_fn(params["lead"][i], x)
+        aux_total = aux_total + a
     if nb > 0:
         def block_fn(carry, bp):
             h, aux = carry
+            block_stats = {}
             for i, spec in enumerate(pattern):
-                h, a = apply_layer_train(bp[f"l{i}"], spec, cfg, h, cos, sin)
+                h, a, st = apply_layer_train(bp[f"l{i}"], spec, cfg, h, cos,
+                                             sin)
                 aux = aux + a
-            return (h, aux), None
+                if st is not None:
+                    block_stats[f"l{i}"] = st
+            return (h, aux), (block_stats or None)
 
         block_fn = _remat(block_fn, cfg.remat_policy)
         if cfg.scan_layers:
-            (x, aux_total), _ = jax.lax.scan(
+            (x, aux_total), block_stats = jax.lax.scan(
                 block_fn, (x, aux_total), params["blocks"]
             )
         else:
+            ys = []
             for bi in range(nb):
                 bp = jax.tree.map(lambda p: p[bi], params["blocks"])
-                (x, aux_total), _ = block_fn((x, aux_total), bp)
+                (x, aux_total), y = block_fn((x, aux_total), bp)
+                ys.append(y)
+            block_stats = (None if ys[0] is None else
+                           jax.tree.map(lambda *a: jnp.stack(a), *ys))
+        if block_stats is not None:
+            loads["blocks"] = {k: v["load"] for k, v in block_stats.items()}
+            per_layer += list(block_stats.values())
     for i, spec in enumerate(tail):
-        x, a = apply_layer_train(params["tail"][i], spec, cfg, x, cos, sin)
+        x, a, st = apply_layer_train(params["tail"][i], spec, cfg, x, cos, sin)
         aux_total = aux_total + a
-    return rmsnorm(params["final_norm"], x, cfg.norm_eps), aux_total
+        if st is not None:
+            loads.setdefault("tail", {})[str(i)] = st["load"]
+            per_layer.append(st)
+    stats = None
+    if per_layer:
+        stats = {
+            "load": loads,
+            "rows": sum(jnp.sum(s["rows"]) for s in per_layer),
+            "rows_max": sum(jnp.sum(s["rows_max"]) for s in per_layer),
+            "groups": jnp.asarray(sum(jnp.size(s["rows"]) for s in per_layer),
+                                  jnp.int32),
+        }
+    return rmsnorm(params["final_norm"], x, cfg.norm_eps), aux_total, stats
 
 
 def _positions(cfg: ModelConfig, batch: Dict[str, jax.Array], S: int, B: int):
@@ -224,9 +301,9 @@ def loss_fn(params, cfg: ModelConfig, batch: Dict[str, jax.Array]
     """Full train forward -> (scalar loss fp32, metrics)."""
     x, B, S = _input_x(params, cfg, batch)
     pos = _positions(cfg, batch, S, B)
-    cos, sin = rope_angles(pos, cfg.resolved_head_dim, cfg.rope_theta,
+    cos, sin = rope_angles(pos, cfg.rope_dim, cfg.rope_theta,
                            cfg.mrope_sections)
-    x, aux = forward_backbone(params, cfg, x, cos, sin)
+    x, aux, stats = forward_backbone(params, cfg, x, cos, sin)
     if cfg.tie_embeddings:
         logits = unembed_logits(params["embed"], x, _dtype(cfg))
     else:
@@ -234,7 +311,10 @@ def loss_fn(params, cfg: ModelConfig, batch: Dict[str, jax.Array]
         logits = shard_act(logits, "batch", None, "model")
     xent = softmax_xent(logits, batch["labels"], mode=cfg.xent_mode)
     loss = xent + cfg.router_aux_coef * aux
-    return loss, {"xent": xent, "aux": aux}
+    metrics = {"xent": xent, "aux": aux}
+    if stats is not None:
+        metrics["moe"] = jax.tree.map(jax.lax.stop_gradient, stats)
+    return loss, metrics
 
 
 def forward_logits(params, cfg: ModelConfig, batch: Dict[str, jax.Array],
@@ -242,9 +322,9 @@ def forward_logits(params, cfg: ModelConfig, batch: Dict[str, jax.Array],
     """Prefill forward (no labels). Returns last-position logits by default."""
     x, B, S = _input_x(params, cfg, batch)
     pos = _positions(cfg, batch, S, B)
-    cos, sin = rope_angles(pos, cfg.resolved_head_dim, cfg.rope_theta,
+    cos, sin = rope_angles(pos, cfg.rope_dim, cfg.rope_theta,
                            cfg.mrope_sections)
-    x, _ = forward_backbone(params, cfg, x, cos, sin)
+    x, _, _ = forward_backbone(params, cfg, x, cos, sin)
     if last_only:
         x = x[:, -1:]
     if cfg.tie_embeddings:
@@ -259,6 +339,7 @@ class DecodeState(NamedTuple):
     blocks: Any            # per-pattern-position states, stacked over nb
     tail: Any              # list of per-layer states
     pos: jax.Array         # scalar int32: next absolute position
+    lead: Any = ()         # states of the leading dense layers
 
 
 def _layer_capacity(cfg: ModelConfig, spec, seq_budget: int) -> int:
@@ -269,6 +350,9 @@ def _layer_capacity(cfg: ModelConfig, spec, seq_budget: int) -> int:
 
 def init_layer_state(cfg: ModelConfig, spec, batch: int, seq_budget: int):
     dt = _dtype(cfg)
+    if spec.mixer == MLA:
+        return init_mla_cache(batch, seq_budget, cfg.kv_lora_rank,
+                              cfg.qk_rope_head_dim, dt)
     if spec.mixer in (ATTN, ATTN_LOCAL):
         return init_cache(
             batch, _layer_capacity(cfg, spec, seq_budget),
@@ -304,15 +388,22 @@ def init_decode_state(cfg: ModelConfig, batch: int, seq_budget: int,
     tail_states = [
         init_layer_state(cfg, spec, batch, seq_budget) for spec in tail
     ]
+    lead = tuple(init_layer_state(cfg, spec, batch, seq_budget)
+                 for spec in cfg.lead_layers())
     return DecodeState(blocks=blocks, tail=tail_states,
-                       pos=jnp.asarray(pos, jnp.int32))
+                       pos=jnp.asarray(pos, jnp.int32), lead=lead)
 
 
 def apply_layer_decode(params, state, spec, cfg: ModelConfig, x, pos, cos, sin):
     dt = _dtype(cfg)
     eps = cfg.norm_eps
     h = rmsnorm(params["norm1"], x, eps)
-    if spec.mixer in (ATTN, ATTN_LOCAL):
+    if spec.mixer == MLA:
+        m, new_state = mla_decode(
+            params["mixer"], h, state, pos, cos, sin, rank=cfg.kv_lora_rank,
+            nope=cfg.qk_nope_head_dim, dtype=dt, eps=eps,
+        )
+    elif spec.mixer in (ATTN, ATTN_LOCAL):
         m, new_state = attn_mod.attention_decode(
             params["mixer"], h, state, pos, cos, sin, dtype=dt, eps=eps,
             window=spec.window, softcap=cfg.attn_logit_softcap,
@@ -327,14 +418,7 @@ def apply_layer_decode(params, state, spec, cfg: ModelConfig, x, pos, cos, sin):
     x = x + m
     if spec.ffn != NONE:
         h = rmsnorm(params["norm2"], x, eps)
-        if spec.ffn == DENSE:
-            f = mlp_apply(params["ffn"], h, cfg.act, dt)
-        else:
-            f, _ = moe_apply(
-                params["ffn"], h, top_k=cfg.top_k,
-                capacity_factor=cfg.capacity_factor, dtype=dt,
-                num_real_experts=cfg.num_experts,
-            )
+        f, _, _ = _ffn(params["ffn"], spec, cfg, h)
         x = x + f
     return x, new_state
 
@@ -352,9 +436,15 @@ def decode_step(params, cfg: ModelConfig, state: DecodeState,
     pos_ids = jnp.broadcast_to(pos[None, None], (B, 1)).astype(jnp.int32)
     if cfg.mrope_sections:
         pos_ids = jnp.broadcast_to(pos_ids[..., None], (B, 1, 3))
-    cos, sin = rope_angles(pos_ids, cfg.resolved_head_dim, cfg.rope_theta,
+    cos, sin = rope_angles(pos_ids, cfg.rope_dim, cfg.rope_theta,
                            cfg.mrope_sections)
 
+    new_lead = []
+    for i, spec in enumerate(cfg.lead_layers()):
+        x, ns = apply_layer_decode(
+            params["lead"][i], state.lead[i], spec, cfg, x, pos, cos, sin
+        )
+        new_lead.append(ns)
     pattern, nb, tail = cfg.scan_split()
     new_blocks = None
     if nb > 0:
@@ -408,4 +498,5 @@ def decode_step(params, cfg: ModelConfig, state: DecodeState,
         logits = unembed_logits(params["embed"], x, dt)
     else:
         logits = x @ params["lm_head"]["w"].astype(dt)
-    return logits, DecodeState(blocks=new_blocks, tail=new_tail, pos=pos + 1)
+    return logits, DecodeState(blocks=new_blocks, tail=new_tail, pos=pos + 1,
+                               lead=tuple(new_lead))

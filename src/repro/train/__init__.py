@@ -1,6 +1,6 @@
 """Training substrate: optimizer, microbatched step, checkpointing, faults."""
 from repro.train.optimizer import OptConfig, adamw_update, init_opt_state, lr_at
-from repro.train.train_step import make_loss_and_grads, make_train_step
+from repro.train.train_step import count_step, make_loss_and_grads, make_train_step
 from repro.train.checkpoint import (
     AsyncCheckpointer,
     restore_arrays,

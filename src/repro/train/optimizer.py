@@ -63,6 +63,16 @@ def global_norm(tree: Any) -> jax.Array:
     return jnp.sqrt(sq)
 
 
+FROZEN_LEAVES = ("router_bias",)
+
+
+def is_frozen(path) -> bool:
+    """Leaves that AdamW and weight decay leave alone: each is updated by a
+    rule of its own (the router's correction bias, ``train_step``)."""
+    last = path[-1] if path else None
+    return getattr(last, "key", None) in FROZEN_LEAVES
+
+
 def adamw_update(
     grads: Any,
     opt_state: Dict[str, Any],
@@ -89,15 +99,20 @@ def adamw_update(
         new_master = ref - lr * delta
         return new_master.astype(p.dtype), mu, nu, new_master
 
+    def held(p, g, mu, nu, master):
+        return p, mu, nu, master
+
     has_master = "master" in opt_state
-    flat_p, treedef = jax.tree.flatten(params)
+    paths, treedef = jax.tree_util.tree_flatten_with_path(params)
+    flat_p = [p for _, p in paths]
     flat_g = treedef.flatten_up_to(grads)
     flat_mu = treedef.flatten_up_to(opt_state["mu"])
     flat_nu = treedef.flatten_up_to(opt_state["nu"])
     flat_ma = (treedef.flatten_up_to(opt_state["master"]) if has_master
                else [None] * len(flat_p))
-    out = [upd(p, g, m, n, ma)
-           for p, g, m, n, ma in zip(flat_p, flat_g, flat_mu, flat_nu, flat_ma)]
+    out = [(held if is_frozen(path) else upd)(p, g, m, n, ma)
+           for (path, p), g, m, n, ma
+           in zip(paths, flat_g, flat_mu, flat_nu, flat_ma)]
     new_params = jax.tree.unflatten(treedef, [o[0] for o in out])
     new_state = {
         "mu": jax.tree.unflatten(treedef, [o[1] for o in out]),

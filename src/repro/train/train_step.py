@@ -6,6 +6,12 @@ Microbatching bounds activation memory: the global batch is split into
 §Perf lever for the 27B model). Remat policy lives inside the model's
 scan-over-blocks. Gradient compression (bf16/int8+EF) optionally wraps the
 accumulated grads before the optimizer.
+
+A model with sigmoid-routed MoE layers (DeepSeek-V3) also returns its
+experts' loads (``metrics["moe"]``), summed over the microbatches; after
+the optimizer the step moves each layer's router correction bias by
+``bias_update_rate`` towards balance. ``count_step`` hands a step's
+expert-row counts to the program's tracing counters.
 """
 from __future__ import annotations
 
@@ -15,6 +21,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import tracing
 from repro.models.model_zoo import Model
 from repro.train import grad_compress
 from repro.train.optimizer import OptConfig, adamw_update
@@ -62,10 +69,55 @@ def make_loss_and_grads(
         )
         inv = 1.0 / num_microbatches
         grads = jax.tree.map(lambda g: (g * inv).astype(jnp.float32), grads)
-        last_metrics = jax.tree.map(lambda m: m[-1], metrics)
+        last_metrics = {
+            k: jax.tree.map(lambda m: m.sum(0) if k == "moe" else m[-1], v)
+            for k, v in metrics.items()
+        }
         return loss_sum * inv, grads, last_metrics
 
     return loss_and_grads
+
+
+def update_router_bias(params: Any, load: Any, rate: float) -> Any:
+    """DeepSeek-V3's auxiliary-loss-free balancing: each layer's correction
+    bias ``b_i += rate * sign(mean load - load_i)``, from the loads counted
+    over the step's tokens (``load`` laid out as ``forward_backbone`` gives
+    it)."""
+    def moved(b, n):
+        step = rate * jnp.sign(jnp.mean(n, axis=-1, keepdims=True) - n)
+        return b + step.astype(b.dtype)
+
+    new = dict(params)
+    if "blocks" in load:
+        new["blocks"] = dict(params["blocks"])
+        for name, n in load["blocks"].items():
+            layer = dict(new["blocks"][name])
+            layer["ffn"] = dict(layer["ffn"],
+                                router_bias=moved(layer["ffn"]["router_bias"], n))
+            new["blocks"][name] = layer
+    if "tail" in load:
+        new["tail"] = list(params["tail"])
+        for i, n in load["tail"].items():
+            layer = dict(new["tail"][int(i)])
+            layer["ffn"] = dict(layer["ffn"],
+                                router_bias=moved(layer["ffn"]["router_bias"], n))
+            new["tail"][int(i)] = layer
+    return new
+
+
+def count_step(metrics: Dict[str, Any]) -> None:
+    """While a trace is taken, adds a step's MoE rows to the program's
+    counters: ``moe.routed_rows`` (rows the held experts computed, over the
+    layers and microbatches), ``moe.expert_rows_max`` (per layer and
+    microbatch, the busiest held expert's rows, summed) and
+    ``moe.expert_row_groups`` (how many such layer-microbatch groups). Reads
+    them back from the device only then."""
+    if "moe" not in metrics or not tracing.active():
+        return
+    m = metrics["moe"]
+    tracing.count("moe.routed_rows", int(m["rows"]))
+    tracing.count("moe.expert_rows_max", int(m["rows_max"]))
+    tracing.count("moe.expert_row_groups", int(m["groups"]))
 
 
 def make_train_step(
@@ -91,6 +143,9 @@ def make_train_step(
         new_params, new_opt, opt_metrics = adamw_update(
             grads, opt_state, params, opt_cfg
         )
+        if "moe" in metrics and model.cfg.bias_update_rate:
+            new_params = update_router_bias(
+                new_params, metrics["moe"]["load"], model.cfg.bias_update_rate)
         metrics = dict(metrics, loss=loss, **opt_metrics)
         if compression == "int8_ef":
             return new_params, new_opt, metrics, new_ef

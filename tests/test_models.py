@@ -70,12 +70,13 @@ def test_arch_prefill_and_decode_smoke(arch):
 
 @pytest.mark.parametrize(
     "arch",
-    ["codeqwen1.5-7b", "falcon-mamba-7b", "recurrentgemma-2b", "gemma3-27b"],
+    ["codeqwen1.5-7b", "falcon-mamba-7b", "recurrentgemma-2b", "gemma3-27b",
+     "moonlight-16b-a3b"],
 )
 def test_decode_matches_teacher_forcing(arch):
     """Token-by-token decode must reproduce the full-sequence forward —
-    validates ring KV caches (global + windowed), mamba and RG-LRU decode
-    states against their train-time scans."""
+    validates ring KV caches (global + windowed), the MLA latent cache,
+    mamba and RG-LRU decode states against their train-time scans."""
     cfg = smoke_config(get_config(arch)).replace(dtype="float32")
     model = build_model(cfg)
     params = model.init(KEY)
@@ -151,4 +152,4 @@ def test_input_specs_cover_all_cells():
             st = model.decode_state_specs(shape)
             assert jax.tree.leaves(st)
         n += 1
-    assert n == 32   # 10 archs x 4 shapes - 8 long_500k skips
+    assert n == 35   # 11 archs x 4 shapes - 9 long_500k skips
